@@ -44,15 +44,17 @@ def main(argv=None) -> int:
 
     csv_rows: List[str] = []
     sections: List[str] = []
+    failed: List[str] = []
     for key in keys:
         mod = MODULES[key]
         print(f"[bench] {key} ...", flush=True)
         t0 = time.perf_counter()
         try:
             sections.append(mod.run(csv_rows))
-        except Exception as e:  # keep the harness alive; record the failure
+        except Exception as e:  # run the rest, record it, exit non-zero
             sections.append(f"## {key}: FAILED\n```\n{e!r}\n```")
             csv_rows.append(f"{key},0,FAILED")
+            failed.append(key)
         print(f"[bench] {key} done in {time.perf_counter()-t0:.1f}s", flush=True)
 
     out_md = os.path.join(os.path.dirname(__file__), "RESULTS.md")
@@ -65,6 +67,9 @@ def main(argv=None) -> int:
     for row in csv_rows:
         print(row)
     print(f"\nwrote {out_md}")
+    if failed:
+        print(f"[bench] FAILED: {', '.join(failed)}", file=sys.stderr)
+        return 1
     return 0
 
 

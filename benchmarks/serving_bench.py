@@ -12,9 +12,9 @@ measurably from ``max_batch >= 4`` on CPU.
 
 Two regression guards ride along:
 
-* **Donation** (``maybe_donate``): donating the cache/state buffers into
+* **Donation** (``donating_jit``): donating the cache/state buffers into
   the fused step must not cost throughput — asserted at >= 0.75x the
-  non-donated fused rate (generous bound; donation is a no-op on CPU).
+  non-donated fused rate (0.4x on the CPU, whose timings are noisy).
 * **Paged KV**: the block-pool layout must stay within striking distance
   of the contiguous fused path (reported as a ratio), while the engine
   section shows the point of paging — peak KV bytes actually allocated for
@@ -48,7 +48,7 @@ from repro.models import model as model_lib
 from repro.serving.engine import ServingEngine, _percentile
 from repro.serving.sampling import SamplingParams, sample
 from repro.serving.step import (init_slot_state, make_decode_sample_step,
-                                maybe_donate)
+                                donating_jit)
 from repro.serving.workload import (bursty_trace, interference_trace,
                                     lookup_friendly_trace)
 
@@ -658,13 +658,8 @@ def run(csv_rows: List[str]) -> str:
             decode, params, cache, B, STEPS, params_s)
         ref_sps = STEPS / ref_s
         fused_sps = _time_fused(fused, cfg, params, B, params_s)
-        if cpu:
-            # maybe_donate is a plain jit on CPU — timing it again would
-            # compile and measure an identical executable
-            donated_sps = fused_sps
-        else:
-            donated = maybe_donate(step_fn, (1, 2))
-            donated_sps = _time_fused(donated, cfg, params, B, params_s)
+        donated = donating_jit(step_fn, (1, 2))
+        donated_sps = _time_fused(donated, cfg, params, B, params_s)
         paged_sps = _time_fused(fused, cfg, params, B, params_s,
                                 layout="paged")
         # regression gates.  On CPU the paged path pays an XLA gather the

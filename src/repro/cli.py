@@ -18,6 +18,8 @@ import argparse
 import json
 import sys
 
+from repro.launch.cache import setup_compilation_cache
+
 
 def _add_common(p, smoke_default=False):
     p.add_argument("--arch", required=True)
@@ -57,6 +59,7 @@ def cmd_cache(args) -> int:
 def cmd_latency(args) -> int:
     from repro.core.profiler import Elana
 
+    setup_compilation_cache()
     out = Elana(args.arch, smoke=args.smoke).measure(
         batch=args.batch, prompt_len=args.prompt, gen_len=args.gen,
         iters=args.iters,
@@ -67,12 +70,14 @@ def cmd_latency(args) -> int:
 
 def cmd_energy(args) -> int:
     from repro.core import energy as energy_lib
-    from repro.core.hardware import get_hardware
+    from repro.core.hardware import get_hardware, hardware_for_device
     from repro.core.profiler import Elana
 
-    hw = get_hardware(args.hardware)
+    setup_compilation_cache()
+    # the spec of the device this run measures on, unless named explicitly
+    hw = get_hardware(args.hardware) if args.hardware else hardware_for_device()
     reader = energy_lib.ProcStatReader(hw.idle_watts, hw.tdp_watts) \
-        if args.hardware == "cpu" else energy_lib.ModelReader(
+        if hw.kind == "cpu" else energy_lib.ModelReader(
             hw.idle_watts, hw.tdp_watts)
     out = Elana(args.arch, smoke=args.smoke).measure(
         batch=args.batch, prompt_len=args.prompt, gen_len=args.gen,
@@ -137,14 +142,17 @@ def cmd_report(args) -> int:
 
 def cmd_dryrun(args) -> int:
     # Heavy import chain + XLA_FLAGS env var: delegate to the launch module
-    # in a fresh interpreter so device count forcing works.
+    # in a fresh interpreter so device count forcing works.  The dry run
+    # analyses 512 forced host devices on the CPU: it never takes a chip
+    # (this parent has not imported JAX, and the child is held to the CPU).
+    import os
     import subprocess
 
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", args.arch,
            "--shape", args.shape]
     if args.multi_pod:
         cmd.append("--multi-pod")
-    return subprocess.call(cmd)
+    return subprocess.call(cmd, env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -173,8 +181,10 @@ def build_parser() -> argparse.ArgumentParser:
         p.add_argument("--prompt", type=int, default=64)
         p.add_argument("--gen", type=int, default=16)
         p.add_argument("--iters", type=int, default=5)
-        p.add_argument("--hardware", default="cpu")
         p.set_defaults(fn=fn)
+    p.add_argument("--hardware", default=None,
+                   help="spec whose idle/TDP watts model the energy "
+                        "(default: the device_kind JAX reports)")
 
     p = sub.add_parser("estimate")
     _add_common(p)

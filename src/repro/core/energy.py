@@ -28,7 +28,14 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 
 class PowerReader:
-    """Interface: instantaneous power in watts, one value per device."""
+    """Interface: instantaneous power in watts, one value per device.
+
+    ``source`` labels what the joules integrated from it are: ``measured``
+    from a power sensor, ``modelled`` from a utilization x TDP model,
+    ``host-cpu`` from the host's CPU load, ``synthetic`` from a test
+    waveform.  Reports carry it next to every joule figure."""
+
+    source = "unknown"
 
     def read_watts(self) -> Sequence[float]:  # pragma: no cover - interface
         raise NotImplementedError
@@ -38,6 +45,8 @@ class PowerReader:
 
 
 class SyntheticReader(PowerReader):
+    source = "synthetic"
+
     def __init__(self, fn: Callable[[float], float], n_devices: int = 1):
         self._fn = fn
         self._n = n_devices
@@ -50,6 +59,8 @@ class SyntheticReader(PowerReader):
 
 class ModelReader(PowerReader):
     """Utilization-scaled TDP model (TPU has no userspace power API)."""
+
+    source = "modelled"
 
     def __init__(self, idle_watts: float, tdp_watts: float,
                  utilization_fn: Optional[Callable[[], float]] = None,
@@ -66,6 +77,8 @@ class ModelReader(PowerReader):
 
 class ProcStatReader(PowerReader):
     """CPU package power proxy from /proc/stat busy fraction × TDP."""
+
+    source = "host-cpu"
 
     def __init__(self, idle_watts: float = 10.0, tdp_watts: float = 65.0):
         self.idle = idle_watts
@@ -91,6 +104,8 @@ class ProcStatReader(PowerReader):
 
 
 class NvmlReader(PowerReader):  # pragma: no cover - needs NVIDIA hardware
+    source = "measured"
+
     def __init__(self, device_indices: Optional[Sequence[int]] = None):
         import pynvml
 
@@ -109,6 +124,8 @@ class NvmlReader(PowerReader):  # pragma: no cover - needs NVIDIA hardware
 
 
 class JtopReader(PowerReader):  # pragma: no cover - needs Jetson hardware
+    source = "measured"
+
     def __init__(self):
         from jtop import jtop
 
@@ -183,6 +200,11 @@ class PowerMonitor:
         self._t0 = 0.0
         self._t1 = 0.0
         self.dropped_reads = 0
+
+    @property
+    def source(self) -> str:
+        """What the joules are (see ``PowerReader.source``)."""
+        return self.reader.source
 
     def _loop(self):
         # absolute-deadline scheduling: waiting ``interval_s`` *after* each
@@ -305,6 +327,10 @@ class DeviceMonitorGroup:
     @property
     def dropped_reads(self) -> int:
         return sum(m.dropped_reads for m in self.monitors)
+
+    @property
+    def source(self) -> str:
+        return "/".join(sorted({m.source for m in self.monitors}))
 
     def __enter__(self) -> "DeviceMonitorGroup":
         # one clock for the group window; the per-device monitors stamp

@@ -7,6 +7,12 @@ HBM, ~50 GB/s/link ICI).  ``eta_*`` are achievable-fraction derates used by
 the latency estimator (sustained / peak — published MLPerf-class systems
 typically sustain 60-80% of peak HBM bandwidth and 40-70% of peak matmul
 throughput at LLM shapes).
+
+Specs are found two ways: by ``name`` (``get_hardware``, what the
+estimator's ``--hardware`` flag takes) and by the ``device_kind`` string
+the JAX runtime reports for a device (``hardware_for_device``, what a
+measured run uses for the device it actually ran on).  A device kind
+missing from the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -34,6 +40,9 @@ class HardwareSpec:
     # rail (jtop), which excludes DRAM/SoC power -> much lower than board TDP.
     rail_tdp_watts: float = 0.0   # 0 -> use tdp_watts
     rail_idle_watts: float = -1.0  # <0 -> use idle_watts
+    # ``jax.Device.device_kind`` this platform reports, as seen on it
+    # ("" = not run here yet: estimator only)
+    device_kind: str = ""
 
     def power_at(self, utilization: float) -> float:
         """Board power at a given utilization (linear idle->TDP model).
@@ -46,10 +55,13 @@ class HardwareSpec:
 
 
 REGISTRY: Dict[str, HardwareSpec] = {}
+BY_DEVICE_KIND: Dict[str, HardwareSpec] = {}
 
 
 def _reg(spec: HardwareSpec) -> HardwareSpec:
     REGISTRY[spec.name] = spec
+    if spec.device_kind:
+        BY_DEVICE_KIND[spec.device_kind] = spec
     return spec
 
 
@@ -99,6 +111,7 @@ TPU_V5E = _reg(HardwareSpec(
     peak_flops_bf16=197e12, hbm_bw=819e9,
     link_bw=50e9, num_links=4,
     tdp_watts=170.0, idle_watts=60.0, mem_bytes=16 * 1000**3,
+    device_kind="TPU v5 lite",  # what a v5e chip reports to JAX
 ))
 
 CPU_DEV = _reg(HardwareSpec(
@@ -108,6 +121,7 @@ CPU_DEV = _reg(HardwareSpec(
     link_bw=0.0, num_links=0,
     tdp_watts=65.0, idle_watts=10.0, mem_bytes=32 * 1000**3,
     eta_compute=0.5, eta_memory=0.5,
+    device_kind="cpu",
 ))
 
 
@@ -115,3 +129,18 @@ def get_hardware(name: str) -> HardwareSpec:
     if name not in REGISTRY:
         raise KeyError(f"unknown hardware {name!r}; known: {sorted(REGISTRY)}")
     return REGISTRY[name]
+
+
+def hardware_for_device(device=None) -> HardwareSpec:
+    """The spec of ``device`` (default: the first JAX device), looked up by
+    the ``device_kind`` the runtime reports."""
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    kind = device.device_kind
+    if kind not in BY_DEVICE_KIND:
+        raise KeyError(
+            f"no hardware spec for device_kind {kind!r}; known: "
+            f"{sorted(BY_DEVICE_KIND)} (add one to core/hardware.py)")
+    return BY_DEVICE_KIND[kind]

@@ -96,6 +96,7 @@ class Elana:
                        ttft_p95_ms=ttft.p95_s * 1e3, tpot_p95_ms=tpot.p95_s * 1e3)
         else:
             mon = energy_lib.PowerMonitor(power_reader)
+            out["energy_source"] = mon.source
             with mon:
                 ttft = lp.ttft(batch, prompt_len, iters=iters)
             e = mon.result()

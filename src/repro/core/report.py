@@ -91,6 +91,9 @@ def serving_client_rows(summary: Dict) -> List[Dict]:
                        ("errors", "client errors")):
         if key in summary:
             rows.append({"Metric": label, "value": round(summary[key], 3)})
+    if "joules_source" in summary:
+        rows.append({"Metric": "energy source",
+                     "value": summary["joules_source"]})
     return rows
 
 

@@ -2,14 +2,22 @@
 (possibly ring-buffered) KV cache, plus the paged (block-pool) variant.
 
 TPU-native design:
+  * heads ride in the lane dimension: the cache is viewed as (B, L, H*D)
+    and a pool as (N, bs, H*D), so every block's last two dimensions are
+    (rows, H*D), whole in the lanes, as the TPU compiler requires.  The
+    kernel walks the kv heads with static lane slices.  On the TPU the
+    reshape from the model's (..., H, D) layout is a relayout copy of the
+    whole cache or pool per call (tiles of the last two dims differ).
   * GQA grouping is exploited for MXU utilization: the G query heads that
     share one kv head are processed together as a (G, D) LHS, so the score
     matmul is (G, D) x (D, bk) instead of G separate vector products.
-  * grid = (batch, kv_heads, kv_blocks); kv innermost, online-softmax
-    accumulators (G x D in fp32) in VMEM scratch — the split-K structure of
+  * grid = (batch, kv_blocks); kv innermost, online-softmax accumulators
+    (Hq x D in fp32) in VMEM scratch — the split-K structure of
     FlashDecoding mapped onto the sequential-grid + scratch idiom.
-  * ring-buffer validity and windowing come from the absolute-position
-    tile, same convention as the flash kernel.
+  * the query positions are scalar-prefetched into SMEM; key positions are
+    blocked as (B, 1, L) rows.  Ring-buffer validity and windowing come
+    from the absolute-position tile, same convention as the flash kernel,
+    and a ragged cache length is padded to whole blocks at position -1.
 
 ``paged_decode_attention`` reuses the same online-softmax body but reads
 K/V straight out of a global block pool: the per-sequence block table is a
@@ -31,60 +39,90 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention.flash_attention import block_and_pad
+
 NEG_INF = -2.0 ** 30
 
 
+def _online_softmax_block(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, valid,
+                          *, num_kv_heads, group, head_dim, softcap, scale):
+    """Fold one (bk, Hkv*D) K/V block into every head's accumulators;
+    ``valid`` is the (1, bk) key mask shared by all heads."""
+    for h in range(num_kv_heads):
+        rows = pl.ds(h * group, group)
+        lanes = pl.ds(h * head_dim, head_dim)
+        q = q_ref[0, rows, :]                       # (G, D)
+        k = k_ref[0, :, lanes]                      # (bk, D)
+        v = v_ref[0, :, lanes]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                   # (G, bk)
+        if softcap > 0.0:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[rows, :]                     # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[rows, :] = l_ref[rows, :] * alpha + jnp.sum(p, axis=1,
+                                                          keepdims=True)
+        acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[rows, :] = m_new
+
+
+def _init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _finalize(o_ref, acc_ref, l_ref):
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+        o_ref.dtype)
+
+
 def _kernel(
-    q_pos_ref,                  # (1, 1) int32
-    k_pos_ref,                  # (1, bk) int32
-    q_ref,                      # (1, 1, G, D)  — G q-heads of this kv head
-    k_ref, v_ref,               # (1, bk, 1, D)
-    o_ref,                      # (1, 1, G, D)
-    acc_ref, m_ref, l_ref,      # VMEM scratch: (G, D), (G, 1), (G, 1) f32
+    q_pos_ref,                  # scalar-prefetch: (B,) int32
+    k_pos_ref,                  # (1, 1, bk) int32
+    q_ref,                      # (1, Hq, D)
+    k_ref, v_ref,               # (1, bk, Hkv*D)
+    o_ref,                      # (1, Hq, D)
+    acc_ref, m_ref, l_ref,      # VMEM scratch: (Hq, D), (Hq, 1), (Hq, 1) f32
     *,
     window: int,
-    softcap: float,
-    scale: float,
     num_kv_blocks: int,
+    **body,
 ):
-    ki = pl.program_id(2)
+    b, ki = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
-    q_pos = q_pos_ref[0, 0]
-    k_pos = k_pos_ref[0]                       # (bk,)
+    q_pos = q_pos_ref[b]
+    k_pos = k_pos_ref[0]                        # (1, bk)
     valid = (k_pos >= 0) & (k_pos <= q_pos)
     if window > 0:
         valid = valid & (q_pos - k_pos < window)
 
     @pl.when(jnp.any(valid))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)    # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                              # (G, bk)
-        if softcap > 0.0:
-            s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid[None, :], jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
+    def _():
+        _online_softmax_block(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+                              valid, **body)
 
     @pl.when(ki == num_kv_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    def _():
+        _finalize(o_ref, acc_ref, l_ref)
+
+
+def _scratch(Hq, D):
+    return [
+        pltpu.VMEM((Hq, D), jnp.float32),
+        pltpu.VMEM((Hq, 1), jnp.float32),
+        pltpu.VMEM((Hq, 1), jnp.float32),
+    ]
 
 
 @functools.partial(
@@ -103,97 +141,81 @@ def decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     B, S, Hq, D = q.shape
-    assert S == 1, "decode kernel is single-token"
+    if S != 1:
+        raise ValueError(f"decode kernel is single-token, got {S} queries")
     _, L, Hkv, _ = k_cache.shape
-    G = Hq // Hkv
-    bk = min(block_kv, L)
-    assert L % bk == 0, (L, bk)
-    nk = L // bk
-    grid = (B, Hkv, nk)
-    # view q as (B, 1, Hkv, G, D) via reshape outside the call
-    qg = q.reshape(B, 1, Hkv * G, D)
+    bk, L_pad = block_and_pad(L, block_kv)
+    pad = ((0, 0), (0, L_pad - L), (0, 0))
+    kf = jnp.pad(k_cache.reshape(B, L, Hkv * D), pad)
+    vf = jnp.pad(v_cache.reshape(B, L, Hkv * D), pad)
+    kp = jnp.pad(k_positions.astype(jnp.int32), ((0, 0), (0, L_pad - L)),
+                 constant_values=-1)[:, None, :]
+    nk = L_pad // bk
 
     kernel = functools.partial(
-        _kernel, window=window, softcap=softcap,
-        scale=1.0 / math.sqrt(D), num_kv_blocks=nk,
+        _kernel, window=window, num_kv_blocks=nk, num_kv_heads=Hkv,
+        group=Hq // Hkv, head_dim=D, softcap=softcap,
+        scale=1.0 / math.sqrt(D),
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bk), lambda b, ki, qp: (b, 0, ki)),
+            pl.BlockSpec((1, Hq, D), lambda b, ki, qp: (b, 0, 0)),
+            pl.BlockSpec((1, bk, Hkv * D), lambda b, ki, qp: (b, ki, 0)),
+            pl.BlockSpec((1, bk, Hkv * D), lambda b, ki, qp: (b, ki, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, Hq, D), lambda b, ki, qp: (b, 0, 0)),
+        scratch_shapes=_scratch(Hq, D),
     )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ki: (b, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, ki: (b, ki)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, 0, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, ki: (b, ki, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, ki: (b, ki, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, 0, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 1, Hq, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q_positions.astype(jnp.int32), k_positions.astype(jnp.int32),
-      qg, k_cache, v_cache)
+    )(q_positions.reshape(B).astype(jnp.int32), kp, q.reshape(B, Hq, D),
+      kf, vf)
     return out.reshape(B, 1, Hq, D)
 
 
 def _paged_kernel(
     bt_ref,                     # scalar-prefetch: (B, nb) int32 block table
-    q_pos_ref,                  # (1, 1) int32
-    q_ref,                      # (1, 1, G, D)
-    k_ref, v_ref,               # (1, bs, 1, D) — physical block via index map
-    o_ref,                      # (1, 1, G, D)
-    acc_ref, m_ref, l_ref,      # VMEM scratch: (G, D), (G, 1), (G, 1) f32
+    q_pos_ref,                  # scalar-prefetch: (B,) int32
+    q_ref,                      # (1, Hq, D)
+    k_ref, v_ref,               # (1, bs, Hkv*D) — physical block via index map
+    o_ref,                      # (1, Hq, D)
+    acc_ref, m_ref, l_ref,      # VMEM scratch: (Hq, D), (Hq, 1), (Hq, 1) f32
     *,
     window: int,
-    softcap: float,
-    scale: float,
     num_kv_blocks: int,
     block_size: int,
+    **body,
 ):
     del bt_ref  # consumed by the index maps
-    ki = pl.program_id(2)
+    b, ki = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def _():
+        _init(acc_ref, m_ref, l_ref)
 
-    q_pos = q_pos_ref[0, 0]
+    q_pos = q_pos_ref[b]
     k_pos = ki * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)[0]
+        jnp.int32, (1, block_size), 1)
     valid = k_pos <= q_pos
     if window > 0:
         valid = valid & (q_pos - k_pos < window)
 
     @pl.when(jnp.any(valid))
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                  # (G, bs)
-        if softcap > 0.0:
-            s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where(valid[None, :], s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid[None, :], jnp.exp(s - m_new[:, None]), 0.0)
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
+    def _():
+        _online_softmax_block(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+                              valid, **body)
 
     @pl.when(ki == num_kv_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    def _():
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(
@@ -211,38 +233,36 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     B, S, Hq, D = q.shape
-    assert S == 1, "decode kernel is single-token"
-    _, bs, Hkv, _ = k_pool.shape
-    G = Hq // Hkv
+    if S != 1:
+        raise ValueError(f"decode kernel is single-token, got {S} queries")
+    N, bs, Hkv, _ = k_pool.shape
     nb = block_tables.shape[1]
-    grid = (B, Hkv, nb)
-    qg = q.reshape(B, 1, Hkv * G, D)
 
     kernel = functools.partial(
-        _paged_kernel, window=window, softcap=softcap,
-        scale=1.0 / math.sqrt(D), num_kv_blocks=nb, block_size=bs,
+        _paged_kernel, window=window, num_kv_blocks=nb, block_size=bs,
+        num_kv_heads=Hkv, group=Hq // Hkv, head_dim=D, softcap=softcap,
+        scale=1.0 / math.sqrt(D),
     )
+    kv_map = lambda b, ki, bt, qp: (bt[b, ki], 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
+        num_scalar_prefetch=2,
+        grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ki, bt: (b, 0)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ki, bt: (b, 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, ki, bt: (bt[b, ki], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda b, h, ki, bt: (bt[b, ki], 0, h, 0)),
+            pl.BlockSpec((1, Hq, D), lambda b, ki, bt, qp: (b, 0, 0)),
+            pl.BlockSpec((1, bs, Hkv * D), kv_map),
+            pl.BlockSpec((1, bs, Hkv * D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki, bt: (b, 0, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hq, D), lambda b, ki, bt, qp: (b, 0, 0)),
+        scratch_shapes=_scratch(Hq, D),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, 1, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), q_positions.astype(jnp.int32),
-      qg, k_pool, v_pool)
+    )(block_tables.astype(jnp.int32), q_positions.reshape(B).astype(jnp.int32),
+      q.reshape(B, Hq, D), k_pool.reshape(N, bs, Hkv * D),
+      v_pool.reshape(N, bs, Hkv * D))
     return out.reshape(B, 1, Hq, D)

@@ -2,8 +2,6 @@
 
 from __future__ import annotations
 
-import jax
-
 from repro.kernels.decode_attention.decode_attention import (
     decode_attention as _pallas,
 )
@@ -12,19 +10,11 @@ from repro.kernels.decode_attention.decode_attention import (
 )
 
 
-def _pick_block(L: int) -> int:
-    for b in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if L % b == 0:
-            return b
-    return 1
-
-
 def decode_attention(q, k_cache, v_cache, *, q_positions, k_positions,
                      window=0, softcap=0.0, interpret=False):
     return _pallas(
         q, k_cache, v_cache, q_positions, k_positions,
-        window=window, softcap=softcap,
-        block_kv=_pick_block(k_cache.shape[1]), interpret=interpret,
+        window=window, softcap=softcap, interpret=interpret,
     )
 
 

@@ -3,13 +3,21 @@ reference implementations.
 
 Backend selection:
   * ``auto``   — Pallas on TPU, reference elsewhere (default).
-  * ``pallas`` — force Pallas (with ``interpret=True`` off-TPU; used by tests).
+  * ``pallas`` — force Pallas.  Off the TPU the kernels run in interpret
+    mode (used by tests); on the TPU they are always compiled.
   * ``xla``    — force the pure-jnp reference.  The multi-pod dry-run uses
     this so ``compiled.cost_analysis()`` sees real HLO FLOPs (a Pallas call
-    is an opaque custom-call to XLA's cost model).
+    is an opaque custom-call to XLA's cost model), and ``chip_smoke.py``
+    uses it as the on-chip reference the kernels are compared against.
 
-The reference implementations live in each kernel's ``ref.py`` and are the
-oracles the Pallas kernels are tested against.
+A kernel never falls back to the reference for a shape it cannot take: it
+raises.  The reference implementations live in each kernel's ``ref.py``
+and are the oracles the Pallas kernels are tested against.
+
+Under a mesh (``sharding.rules.use_mesh``) the attention kernels run once
+per device through ``shard_map``: XLA cannot partition a Pallas call, so
+each device gets its batch rows and its heads as the sharding rules place
+them (``batch`` and ``act_heads``), and runs the kernel on those alone.
 """
 
 from __future__ import annotations
@@ -18,31 +26,35 @@ import contextlib
 import threading
 
 import jax
+from jax.sharding import PartitionSpec as P
+
+from repro.sharding import rules
+
+BACKENDS = ("auto", "pallas", "xla")
 
 
 class _State(threading.local):
     def __init__(self):
-        self.backend = "auto"  # auto | pallas | xla
-        self.interpret = False
+        self.backend = "auto"
 
 
 _STATE = _State()
 
 
-def set_backend(backend: str, interpret: bool = False) -> None:
-    assert backend in ("auto", "pallas", "xla"), backend
+def set_backend(backend: str) -> None:
+    if backend not in BACKENDS:
+        raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
     _STATE.backend = backend
-    _STATE.interpret = interpret
 
 
 @contextlib.contextmanager
-def use_backend(backend: str, interpret: bool = False):
-    prev = (_STATE.backend, _STATE.interpret)
-    set_backend(backend, interpret)
+def use_backend(backend: str):
+    prev = _STATE.backend
+    set_backend(backend)
     try:
         yield
     finally:
-        _STATE.backend, _STATE.interpret = prev
+        _STATE.backend = prev
 
 
 def _use_pallas() -> bool:
@@ -54,7 +66,33 @@ def _use_pallas() -> bool:
 
 
 def _interpret() -> bool:
-    return _STATE.interpret or jax.default_backend() != "tpu"
+    """Interpret mode exactly when no TPU compiles the kernel."""
+    return jax.default_backend() != "tpu"
+
+
+def _per_device(kernel, q, k, v, rows, *, kv_rows: bool = True):
+    """``kernel(q, k, v, *rows)`` once per device of the ambient mesh.
+
+    ``q`` is (B, S, Hq, D) and ``k``/``v`` carry their kv heads at dim 2;
+    ``rows`` are per-sequence (B, ·) operands.  Batch rows and heads split
+    as the rules shard ``batch`` and ``act_heads``; the kv heads follow the
+    query heads (so a device's query heads find their GQA kv head locally),
+    and stay whole when they do not divide.  ``kv_rows=False`` keeps k/v
+    whole along dim 0 (a paged pool is indexed by block, not by row)."""
+    mesh = rules.current_mesh()
+    if mesh is None:
+        return kernel(q, k, v, *rows)
+    logical = ("batch", None, "act_heads", None)
+    batch, _, heads, _ = rules.logical_to_pspec(logical, dims=q.shape,
+                                                mesh=mesh)
+    if rules.logical_to_pspec(logical, dims=k.shape, mesh=mesh)[2] != heads:
+        heads = None
+    q_spec = P(batch, None, heads, None)
+    kv_spec = P(batch if kv_rows else None, None, heads, None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=(q_spec, kv_spec, kv_spec) + (P(batch, None),) * len(rows),
+        out_specs=q_spec, check_vma=False)(q, k, v, *rows)
 
 
 # --------------------------------------------------------------------------
@@ -65,11 +103,11 @@ def flash_attention(q, k, v, *, q_positions, k_positions, causal, window=0,
                     softcap=0.0):
     if _use_pallas():
         from repro.kernels.flash_attention import ops
-        return ops.flash_attention(
-            q, k, v, q_positions=q_positions, k_positions=k_positions,
-            causal=causal, window=window, softcap=softcap,
-            interpret=_interpret(),
-        )
+        return _per_device(
+            lambda q, k, v, qp, kp: ops.flash_attention(
+                q, k, v, q_positions=qp, k_positions=kp, causal=causal,
+                window=window, softcap=softcap, interpret=_interpret()),
+            q, k, v, (q_positions, k_positions))
     from repro.kernels.flash_attention import ref
     # avoid materializing S x T fp32 scores for long sequences on the XLA
     # path (threshold lowered 4096^2 -> 2048^2 in EXPERIMENTS §Perf llava
@@ -90,11 +128,11 @@ def decode_attention(q, k_cache, v_cache, *, q_positions, k_positions,
                      window=0, softcap=0.0):
     if _use_pallas():
         from repro.kernels.decode_attention import ops
-        return ops.decode_attention(
-            q, k_cache, v_cache, q_positions=q_positions,
-            k_positions=k_positions, window=window, softcap=softcap,
-            interpret=_interpret(),
-        )
+        return _per_device(
+            lambda q, k, v, qp, kp: ops.decode_attention(
+                q, k, v, q_positions=qp, k_positions=kp, window=window,
+                softcap=softcap, interpret=_interpret()),
+            q, k_cache, v_cache, (q_positions, k_positions))
     from repro.kernels.decode_attention import ref
     return ref.decode_attention(
         q, k_cache, v_cache, q_positions=q_positions, k_positions=k_positions,
@@ -107,11 +145,11 @@ def paged_decode_attention(q, k_pool, v_pool, *, block_tables, q_positions,
     """Decode attention over a paged (block-pool) KV cache."""
     if _use_pallas():
         from repro.kernels.decode_attention import ops
-        return ops.paged_decode_attention(
-            q, k_pool, v_pool, block_tables=block_tables,
-            q_positions=q_positions, window=window, softcap=softcap,
-            interpret=_interpret(),
-        )
+        return _per_device(
+            lambda q, k, v, bt, qp: ops.paged_decode_attention(
+                q, k, v, block_tables=bt, q_positions=qp, window=window,
+                softcap=softcap, interpret=_interpret()),
+            q, k_pool, v_pool, (block_tables, q_positions), kv_rows=False)
     from repro.kernels.decode_attention import ref
     return ref.paged_decode_attention(
         q, k_pool, v_pool, block_tables=block_tables,

@@ -11,7 +11,6 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.flash_attention import (
@@ -19,21 +18,13 @@ from repro.kernels.flash_attention.flash_attention import (
 )
 
 
-def _pick_blocks(S: int, T: int):
-    bq = 128 if S % 128 == 0 else max(g for g in (64, 32, 16, 8, 4, 2, 1) if S % g == 0)
-    bk = 256 if T % 256 == 0 else max(g for g in (128, 64, 32, 16, 8, 4, 2, 1) if T % g == 0)
-    return min(bq, S), min(bk, T)
-
-
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8)
 )
 def _attn(q, k, v, q_positions, k_positions, causal, window, softcap, interpret):
-    bq, bk = _pick_blocks(q.shape[1], k.shape[1])
     return _pallas_fwd(
         q, k, v, q_positions, k_positions,
-        causal=causal, window=window, softcap=softcap,
-        block_q=bq, block_kv=bk, interpret=interpret,
+        causal=causal, window=window, softcap=softcap, interpret=interpret,
     )
 
 

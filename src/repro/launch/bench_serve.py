@@ -30,19 +30,22 @@ from repro.configs import get_config
 from repro.core import report
 from repro.core.energy import (DeviceMonitorGroup, ModelReader, PowerMonitor,
                                ProcStatReader, SyntheticReader)
-from repro.launch.mesh import make_host_mesh, make_tp_mesh
+from repro.core.hardware import hardware_for_device
+from repro.launch.cache import setup_compilation_cache
+from repro.launch.mesh import make_tp_mesh
 from repro.models import model as model_lib
 from repro.serving.engine import ServingEngine
 from repro.serving.loadgen import LoadSpec, prewarm_engine, run_load
 from repro.serving.server import start_http_server
-from repro.sharding import rules
 
 
 def _make_reader(kind: str):
     if kind == "proc":
         return ProcStatReader()
     if kind == "model":
-        return ModelReader(idle_watts=10.0, tdp_watts=65.0)
+        # idle/TDP watts of the device this process serves on
+        hw = hardware_for_device()
+        return ModelReader(idle_watts=hw.idle_watts, tdp_watts=hw.tdp_watts)
     if kind == "synthetic":
         import math
 
@@ -124,6 +127,7 @@ def main(argv=None) -> int:
                          "on CPU force a multi-device host with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
     args = ap.parse_args(argv)
+    setup_compilation_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.power_reader == "none":
@@ -137,34 +141,33 @@ def main(argv=None) -> int:
                                interval_s=args.power_interval)
 
     tp_mesh = make_tp_mesh(args.tp) if args.tp > 1 else None
-    with rules.use_mesh(make_host_mesh() if tp_mesh is None else None):
-        params, param_axes = model_lib.init(cfg, jax.random.PRNGKey(args.seed))
-        engine = ServingEngine(cfg, params, max_batch=args.max_batch,
-                               max_len=args.max_len, seed=args.seed,
-                               mesh=tp_mesh,
-                               param_axes=(param_axes if tp_mesh is not None
-                                           else None),
-                               prefill_chunk=args.prefill_chunk)
-        if monitor is not None:
-            engine.attach_monitor(monitor)
-        prewarm_engine(engine, prompt_len=args.prompt_len,
-                       concurrency=min(args.concurrency, args.max_batch),
-                       vocab_size=cfg.vocab_size, seed=args.seed)
-        handle = start_http_server(engine, model_name=cfg.name)
-        spec = LoadSpec(mode=args.mode, concurrency=args.concurrency,
-                        qps=args.qps, warmup_s=args.warmup_s,
-                        duration_s=args.duration_s,
-                        max_requests=args.max_requests,
-                        prompt_len=args.prompt_len, max_new=args.max_new,
-                        temperature=args.temperature,
-                        vocab_size=cfg.vocab_size, seed=args.seed)
-        print(f"# driving {handle.url} : mode={spec.mode} "
-              f"warmup={spec.warmup_s}s window={spec.duration_s}s")
-        try:
-            result = run_load(handle.url, spec, monitor=monitor)
-            engine_summary = handle.server.summary()
-        finally:
-            handle.close()
+    params, param_axes = model_lib.init(cfg, jax.random.PRNGKey(args.seed))
+    engine = ServingEngine(cfg, params, max_batch=args.max_batch,
+                           max_len=args.max_len, seed=args.seed,
+                           mesh=tp_mesh,
+                           param_axes=(param_axes if tp_mesh is not None
+                                       else None),
+                           prefill_chunk=args.prefill_chunk)
+    if monitor is not None:
+        engine.attach_monitor(monitor)
+    prewarm_engine(engine, prompt_len=args.prompt_len,
+                   concurrency=min(args.concurrency, args.max_batch),
+                   vocab_size=cfg.vocab_size, seed=args.seed)
+    handle = start_http_server(engine, model_name=cfg.name)
+    spec = LoadSpec(mode=args.mode, concurrency=args.concurrency,
+                    qps=args.qps, warmup_s=args.warmup_s,
+                    duration_s=args.duration_s,
+                    max_requests=args.max_requests,
+                    prompt_len=args.prompt_len, max_new=args.max_new,
+                    temperature=args.temperature,
+                    vocab_size=cfg.vocab_size, seed=args.seed)
+    print(f"# driving {handle.url} : mode={spec.mode} "
+          f"warmup={spec.warmup_s}s window={spec.duration_s}s")
+    try:
+        result = run_load(handle.url, spec, monitor=monitor)
+        engine_summary = handle.server.summary()
+    finally:
+        handle.close()
 
     summary = result.summary
     print(json.dumps(summary, indent=2, default=float))

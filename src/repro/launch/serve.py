@@ -25,7 +25,9 @@ from repro.configs import get_config
 from repro.core import report
 from repro.core.energy import (DeviceMonitorGroup, ModelReader, PowerMonitor,
                                ProcStatReader, SyntheticReader)
-from repro.launch.mesh import make_host_mesh, make_tp_mesh
+from repro.core.hardware import hardware_for_device
+from repro.launch.cache import setup_compilation_cache
+from repro.launch.mesh import make_tp_mesh
 from repro.models import model as model_lib
 from repro.serving.engine import ServingEngine
 from repro.models import cache as cache_lib
@@ -33,14 +35,15 @@ from repro.serving.workload import (LengthDist, OpenLoopDriver, WorkloadSpec,
                                     bursty_trace, estimate_concurrency,
                                     lookup_friendly_trace, poisson_trace,
                                     replay_trace, shared_prefix_trace)
-from repro.sharding import rules
 
 
 def _make_reader(kind: str):
     if kind == "proc":
         return ProcStatReader()
     if kind == "model":
-        return ModelReader(idle_watts=10.0, tdp_watts=65.0)
+        # idle/TDP watts of the device this process serves on
+        hw = hardware_for_device()
+        return ModelReader(idle_watts=hw.idle_watts, tdp_watts=hw.tdp_watts)
     if kind == "synthetic":
         return SyntheticReader(lambda t: 42.0)
     return None
@@ -202,6 +205,7 @@ def main(argv=None) -> int:
                          "XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=N)")
     args = ap.parse_args(argv)
+    setup_compilation_cache()
     if args.prefix_cache and args.cache_layout != "paged":
         ap.error("--prefix-cache requires --cache-layout paged")
     if args.preemption != "off" and args.cache_layout != "paged":
@@ -275,69 +279,69 @@ def main(argv=None) -> int:
 
     monitor = _make_monitor(args.power_reader, args.tp)
     # --tp > 1: the engine owns its (tp,) mesh (entered around every
-    # trace/dispatch), so the ambient host data-mesh stays out of the way
+    # trace/dispatch); --tp 1 runs unsharded on the first device
     tp_mesh = make_tp_mesh(args.tp) if args.tp > 1 else None
-    with rules.use_mesh(make_host_mesh() if tp_mesh is None else None):
-        params, param_axes = model_lib.init(cfg, jax.random.PRNGKey(args.seed))
-        engine = ServingEngine(cfg, params, max_batch=args.max_batch,
-                               max_len=args.max_len, seed=args.seed,
-                               mesh=tp_mesh,
-                               param_axes=(param_axes if tp_mesh is not None
-                                           else None),
-                               cache_layout=args.cache_layout,
-                               kv_block_size=args.kv_block_size,
-                               kv_num_blocks=kv_num_blocks,
-                               prefill_chunk=args.prefill_chunk,
-                               prefill_budget=args.prefill_budget,
-                               prefix_cache=args.prefix_cache,
-                               preemption=args.preemption,
-                               unified_step=args.unified_step == "on",
-                               pad_side=args.pad_side,
-                               speculative=args.speculative,
-                               spec_tokens=args.spec_tokens)
-        if args.http_port:
-            from repro.serving.server import start_http_server
+    params, param_axes = model_lib.init(cfg, jax.random.PRNGKey(args.seed))
+    engine = ServingEngine(cfg, params, max_batch=args.max_batch,
+                           max_len=args.max_len, seed=args.seed,
+                           mesh=tp_mesh,
+                           param_axes=(param_axes if tp_mesh is not None
+                                       else None),
+                           cache_layout=args.cache_layout,
+                           kv_block_size=args.kv_block_size,
+                           kv_num_blocks=kv_num_blocks,
+                           prefill_chunk=args.prefill_chunk,
+                           prefill_budget=args.prefill_budget,
+                           prefix_cache=args.prefix_cache,
+                           preemption=args.preemption,
+                           unified_step=args.unified_step == "on",
+                           pad_side=args.pad_side,
+                           speculative=args.speculative,
+                           spec_tokens=args.spec_tokens)
+    if args.http_port:
+        from repro.serving.server import start_http_server
 
-            if monitor is not None:
-                engine.attach_monitor(monitor)
-                monitor.__enter__()
-            handle = start_http_server(engine, host=args.http_host,
-                                       port=args.http_port,
-                                       model_name=cfg.name)
-            print(f"# serving {cfg.name} at {handle.url} "
-                  f"(POST /v1/completions; Ctrl-C to stop)")
-            try:
-                while True:
-                    time.sleep(1.0)
-            except KeyboardInterrupt:
-                pass
-            handle.close()
-            if monitor is not None:
-                monitor.__exit__(None, None, None)
-            summary = handle.server.summary()
-            print(json.dumps(summary, indent=2, default=float))
-            print("\n## Latency percentiles\n")
-            print(report.to_markdown(report.serving_summary_rows(summary)))
-            return 0
-        driver = OpenLoopDriver(engine, arrivals)
         if monitor is not None:
             engine.attach_monitor(monitor)
-            with monitor:
-                finished = driver.run()
-        else:
-            finished = driver.run()
-
-        summary = engine.latency_summary()
-        print(json.dumps(summary, indent=2))
+            monitor.__enter__()
+        handle = start_http_server(engine, host=args.http_host,
+                                   port=args.http_port,
+                                   model_name=cfg.name)
+        print(f"# serving {cfg.name} at {handle.url} "
+              f"(POST /v1/completions; Ctrl-C to stop)")
+        try:
+            while True:
+                time.sleep(1.0)
+        except KeyboardInterrupt:
+            pass
+        handle.close()
+        if monitor is not None:
+            monitor.__exit__(None, None, None)
+        summary = handle.server.summary()
+        print(json.dumps(summary, indent=2, default=float))
         print("\n## Latency percentiles\n")
         print(report.to_markdown(report.serving_summary_rows(summary)))
-        throughput = report.serving_throughput_rows(summary)
-        if throughput:
-            print("\n## Step economics\n")
-            print(report.to_markdown(throughput))
-        print("\n## Per-request (energy attributed per token window)\n")
-        print(report.to_markdown(report.serving_request_rows(
-            sorted(finished, key=lambda r: r.uid))))
+        return 0
+    driver = OpenLoopDriver(engine, arrivals)
+    if monitor is not None:
+        engine.attach_monitor(monitor)
+        with monitor:
+            finished = driver.run()
+    else:
+        finished = driver.run()
+
+    summary = engine.latency_summary()
+    print(json.dumps(summary, indent=2))
+    print("\n## Latency percentiles\n")
+    print(report.to_markdown(report.serving_summary_rows(summary)))
+    throughput = report.serving_throughput_rows(summary)
+    if throughput:
+        print("\n## Step economics\n")
+        print(report.to_markdown(throughput))
+    print(f"\n## Per-request ({summary.get('joules_source', 'no')} energy "
+          f"attributed per token window)\n")
+    print(report.to_markdown(report.serving_request_rows(
+        sorted(finished, key=lambda r: r.uid))))
     return 0
 
 

@@ -40,7 +40,7 @@ from repro.sharding import rules as rules_lib
 from repro.serving.sampling import SamplingParams, sample
 from repro.serving.step import (init_slot_state, invalidate_slot,
                                 make_decode_sample_step, make_engine_step,
-                                make_spec_decode_step, maybe_donate)
+                                make_spec_decode_step, donating_jit)
 
 _RING = 64  # host-side token ring buffer depth (tokens per slot per flush)
 
@@ -363,11 +363,11 @@ class ServingEngine:
             self._state = jax.device_put(
                 self._state, partition_lib.replicated(mesh))
         if self.spec_k:
-            self._step = self._counted(maybe_donate(
+            self._step = self._counted(donating_jit(
                 make_spec_decode_step(cfg, max_len, k_max=self.top_k_max,
                                       spec_k=self.spec_k), (1, 2)))
         else:
-            self._step = self._counted(maybe_donate(
+            self._step = self._counted(donating_jit(
                 make_decode_sample_step(cfg, max_len, k_max=self.top_k_max),
                 (1, 2)))
         # unified mixed prefill/decode step: one dispatch advances the whole
@@ -380,7 +380,7 @@ class ServingEngine:
             # static packed-frontier width: the budget bounds per-step chunk
             # work, and no cursor can hold more than max_len - 1 tokens
             self._chunk_width = min(self.chunk_budget, max(max_len - 1, 1))
-            self._unified = self._counted(maybe_donate(
+            self._unified = self._counted(donating_jit(
                 make_engine_step(cfg, max_len, k_max=self.top_k_max,
                                  spec_k=self.spec_k), (1, 3)))
         # admission prefill: the n-row cache template is built *inside* the
@@ -410,15 +410,15 @@ class ServingEngine:
                 lengths=lengths)
             return logits, self._merge_admitted(cache, part, slots)
 
-        self._chunk_contig = self._counted(maybe_donate(
+        self._chunk_contig = self._counted(donating_jit(
             lambda p, batch, start, slots, cache, lengths=None: _chunk_body(
                 p, batch, start, slots, cache, None, lengths), (4,)))
-        self._chunk_paged = self._counted(maybe_donate(_chunk_body, (4,)))
+        self._chunk_paged = self._counted(donating_jit(_chunk_body, (4,)))
         # admission-time reset of one slot's cache row to init values (the
         # unchunked path resets implicitly by overwriting the whole row at
         # prefill; a chunk only writes its own span, so stale positions /
         # recurrent state from the previous occupant must be cleared first)
-        self._reset_rows = self._counted(maybe_donate(
+        self._reset_rows = self._counted(donating_jit(
             lambda cache, slots: self._merge_admitted(
                 cache,
                 self._graft_pools(
@@ -1654,6 +1654,7 @@ class ServingEngine:
         if self.monitor is not None:
             total_j = sum(r.joules for r in self.finished)
             summary["joules_total"] = total_j
+            summary["joules_source"] = self.monitor.source
             summary["joules_per_request"] = total_j / max(
                 len(self.finished), 1)
             summary["joules_per_token"] = total_j / max(out_tokens, 1)

@@ -159,6 +159,7 @@ def summarize(records: List[ClientRecord], window: Tuple[float, float],
         res = monitor.result()
         attributed = attribute_energy(records, monitor)
         summary["joules_total"] = res.joules
+        summary["joules_source"] = monitor.source
         summary["joules_attributed"] = attributed
         summary["avg_watts"] = res.avg_watts
         summary["joules_per_request"] = res.joules / max(len(records), 1)

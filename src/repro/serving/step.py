@@ -107,16 +107,15 @@ def invalidate_slot(state: Dict[str, jax.Array], slot: int,
     return state
 
 
-def maybe_donate(fn: Callable, argnums: Tuple[int, ...]) -> Callable:
-    """``jax.jit`` with buffer donation where the backend supports it.
+def donating_jit(fn: Callable, argnums: Tuple[int, ...]) -> Callable:
+    """``jax.jit`` with buffer donation.
 
     Donating the fused step's cache/state buffers lets XLA update the KV
-    cache in place instead of allocating a fresh copy every step.  CPU has
-    no donation support (jax would warn and ignore it), so fall back to a
-    plain jit there.
+    cache in place instead of allocating a fresh copy every step.  Every
+    backend donates, the CPU included, so the tests exercise the same
+    buffer lifetimes as the chip: a donated buffer the host still reads
+    fails there as it would on the TPU ("Array has been deleted").
     """
-    if jax.default_backend() == "cpu":
-        return jax.jit(fn)
     return jax.jit(fn, donate_argnums=argnums)
 
 

@@ -104,6 +104,15 @@ def test_power_monitor_integrates_constant_power():
     assert res.joules == pytest.approx(200.0 * res.duration_s, rel=0.01)
 
 
+def test_power_monitors_label_what_their_joules_are():
+    model = energy_lib.ModelReader(idle_watts=60.0, tdp_watts=170.0)
+    host = energy_lib.ProcStatReader()
+    assert energy_lib.PowerMonitor(model).source == "modelled"
+    assert energy_lib.PowerMonitor(host).source == "host-cpu"
+    assert energy_lib.DeviceMonitorGroup(
+        [model, host]).source == "host-cpu/modelled"
+
+
 def test_power_monitor_window_average():
     # power ramps 0 -> 100 W linearly over the window: average ~50 W
     reader = energy_lib.SyntheticReader(lambda t: min(t / 0.2, 1.0) * 100.0)
@@ -213,6 +222,18 @@ def test_estimator_naive_pp_power_model():
         mode="naive_pp", batch=1, prompt_len=512, gen_len=64)
     hw = get_hardware("a6000")
     assert est.tpot.avg_watts < 1.5 * hw.tdp_watts  # not 4 busy GPUs
+
+
+def test_hardware_for_device_keys_on_device_kind():
+    from types import SimpleNamespace
+
+    from repro.core.hardware import TPU_V5E, hardware_for_device
+
+    assert hardware_for_device(
+        SimpleNamespace(device_kind="TPU v5 lite")) is TPU_V5E
+    assert hardware_for_device().name == "cpu"  # the test host's device
+    with pytest.raises(KeyError, match="no hardware spec"):
+        hardware_for_device(SimpleNamespace(device_kind="TPU v99"))
 
 
 # -- HLO parsing ---------------------------------------------------------------

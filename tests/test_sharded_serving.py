@@ -97,6 +97,25 @@ def test_tp_stream_equivalence(small_model, layout, chunk, spec, temperature):
 
 
 @multidevice
+@pytest.mark.parametrize("layout,chunk", [
+    ("contiguous", 0), ("contiguous", 8), ("paged", 8)])
+def test_tp_pallas_kernels_per_device(small_model, layout, chunk):
+    """The Pallas attention kernels run once per device under the tp mesh
+    (``dispatch._per_device``): tp=2 streams with the kernels match tp=1
+    with the kernels, for every kernel the engine calls."""
+    from repro.kernels import dispatch
+
+    cfg, params, axes = small_model
+    arrivals = _arrivals(cfg, n=4)
+    kw = dict(cache_layout=layout, prefill_chunk=chunk)
+    with dispatch.use_backend("pallas"):
+        _, base = _streams(cfg, params, axes, arrivals, 1, **kw)
+        _, got = _streams(cfg, params, axes, arrivals, 2, **kw)
+    assert len(base) == len(arrivals)
+    assert got == base, (layout, chunk)
+
+
+@multidevice
 @pytest.mark.parametrize("spec", ["off", "lookup"])
 def test_tp_preemption_equivalence(small_model, spec):
     """An overcommitted pool preempts and recomputes identically under a

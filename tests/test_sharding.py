@@ -17,6 +17,35 @@ from repro.sharding import rules
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
+@pytest.mark.parametrize("build", [
+    lambda m: m.make_host_mesh(),
+    lambda m: m.make_tp_mesh(1),
+    lambda m: m.make_mesh((1, 1), ("data", "model")),
+])
+def test_meshes_have_only_auto_axes(build):
+    """``with_sharding_constraint`` (``rules.shard``) only accepts Auto mesh
+    axes; ``jax.make_mesh`` defaults to Explicit ones."""
+    from jax.sharding import AxisType
+    from repro.launch import mesh as mesh_lib
+
+    assert set(build(mesh_lib).axis_types) == {AxisType.Auto}
+
+
+@pytest.mark.parametrize("multi_pod", [False, True])
+def test_production_mesh_asks_for_auto_axes(monkeypatch, multi_pod):
+    """The 256/512-chip production meshes cannot be built here; check the
+    axis types they ask ``jax.make_mesh`` for."""
+    from jax.sharding import AxisType
+    from repro.launch import mesh as mesh_lib
+
+    asked = []
+    monkeypatch.setattr(mesh_lib.jax, "make_mesh",
+                        lambda shape, axes, **kw: asked.append((axes, kw)))
+    mesh_lib.make_production_mesh(multi_pod=multi_pod)
+    (axes, kw), = asked
+    assert kw["axis_types"] == (AxisType.Auto,) * len(axes)
+
+
 def test_logical_to_pspec_no_mesh_is_empty():
     assert rules.logical_to_pspec(("embed", "ffn")) == PartitionSpec()
 
@@ -52,12 +81,13 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
     import numpy as np
     from jax.sharding import PartitionSpec
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models import model as model_lib
     from repro.sharding import partition, rules
     from repro.training import step as step_lib, checkpoint as ckpt_lib
     from repro.training.optimizer import AdamW, constant_schedule
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = get_config("tinyllama-1.1b", smoke=True).replace(
         d_model=64, num_heads=8, num_kv_heads=4, d_ff=128)
     out = {}
@@ -88,7 +118,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent("""
         d = tempfile.mkdtemp()
         ckpt_lib.save(d, 1, {"params": state.params})
 
-    mesh2 = jax.make_mesh((8,), ("data",))
+    mesh2 = make_mesh((8,), ("data",))
     with rules.use_mesh(mesh2):
         shapes2, axes2 = model_lib.param_axes(cfg)
         sh2 = partition.param_shardings(axes2, shapes2, mesh2)

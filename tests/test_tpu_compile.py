@@ -1,0 +1,103 @@
+"""Compile-only checks of the main-path attention kernels for a TPU v5e.
+
+The TPU compiler is installed without a chip; it compiles for a described
+``v5e:2x2`` topology and refuses what the chip would refuse (block shapes
+off the (8, 128) tiling, too much VMEM), which interpret mode cannot see.
+Shapes are ``qwen1.5-0.5b``'s in bf16 at the engine's serving sizes.
+Nothing runs: these tests say a kernel compiles, not that it is right or
+fast (``test_kernels.py`` checks results in interpret mode).
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library, and under
+several test workers only the worker given this file may load it.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.hardware import TPU_V5E, hardware_for_device
+from repro.kernels.decode_attention import ops as decode_ops
+from repro.kernels.flash_attention import ops as flash_ops
+
+B, H, D = 8, 16, 64          # qwen1.5-0.5b: 16 MHA heads of 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiled_hlo(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash(q, k, v, qp, kp):
+    return flash_ops.flash_attention(q, k, v, q_positions=qp, k_positions=kp,
+                                     causal=True)
+
+
+BF, I32 = jnp.bfloat16, jnp.int32
+
+
+@pytest.mark.parametrize("S,T", [
+    (128, 128),           # a 128-token prompt prefill
+    (32, 1024 + 32),      # a packed chunk row: cache plus chunk keys
+    (255, 256 + 255),     # the unified step's frontier at --max-len 256
+])
+def test_flash_attention_compiles_for_v5e(one_chip, S, T):
+    hlo = _compiled_hlo(_flash, one_chip, ((B, S, H, D), BF),
+                        ((B, T, H, D), BF), ((B, T, H, D), BF),
+                        ((B, S), I32), ((B, T), I32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_decode_attention_compiles_for_v5e(one_chip):
+    L = 1024              # contiguous cache at --max-len 1024
+    hlo = _compiled_hlo(
+        lambda q, k, v, qp, kp: decode_ops.decode_attention(
+            q, k, v, q_positions=qp, k_positions=kp),
+        one_chip, ((B, 1, H, D), BF), ((B, L, H, D), BF),
+        ((B, L, H, D), BF), ((B, 1), I32), ((B, L), I32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_paged_decode_attention_compiles_for_v5e(one_chip):
+    bs, nb = 16, 64       # 16-token blocks, 1024 tokens per row
+    N = B * nb + 1        # worst-case pool plus the garbage block
+    hlo = _compiled_hlo(
+        lambda q, k, v, bt, qp: decode_ops.paged_decode_attention(
+            q, k, v, block_tables=bt, q_positions=qp),
+        one_chip, ((B, 1, H, D), BF), ((N, bs, H, D), BF),
+        ((N, bs, H, D), BF), ((B, nb), I32), ((B, 1), I32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_described_v5e_has_a_hardware_spec(topo):
+    assert hardware_for_device(topo.devices[0]) is TPU_V5E

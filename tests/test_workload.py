@@ -77,3 +77,19 @@ def test_benchmark_run_rejects_unknown_keys(capsys):
     assert e.value.code == 2
     err = capsys.readouterr().err
     assert "unknown module key" in err and "table2" in err
+
+
+def test_benchmark_run_records_a_failing_module_and_exits_nonzero(
+        monkeypatch, tmp_path, capsys):
+    from benchmarks import run as bench_run
+
+    def broken(csv_rows):
+        raise RuntimeError("boom")
+
+    monkeypatch.setitem(bench_run.MODULES, "table2",
+                        type("Broken", (), {"run": staticmethod(broken)}))
+    # RESULTS.md is written beside the module: keep the checked-in one
+    monkeypatch.setattr(bench_run, "__file__", str(tmp_path / "run.py"))
+    assert bench_run.main(["--only", "table2"]) == 1
+    assert "table2: FAILED" in (tmp_path / "RESULTS.md").read_text()
+    assert "table2,0,FAILED" in capsys.readouterr().out
