@@ -102,7 +102,6 @@ def serving_throughput_rows(summary: Dict) -> List[Dict]:
     device dispatches it took (the unified mixed step targets <= 2)."""
     rows = []
     for key, label in (("tokens_per_sec", "tokens/s"),
-                       ("decode_tokens_per_sec", "decode tokens/s"),
                        ("prefill_tokens_per_sec", "prefill tokens/s"),
                        ("steps_per_sec", "steps/s"),
                        ("tokens_per_dispatch", "tokens/dispatch"),

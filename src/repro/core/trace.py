@@ -1,9 +1,11 @@
-"""Kernel-level timeline profiling (paper §2.5), exported for Perfetto.
+"""Timelines: the serving engine's own spans, and an estimated op timeline.
 
-Two paths, mirroring the paper's PyTorch-Profiler→Perfetto flow:
-
-* ``capture_jax_trace`` — wraps ``jax.profiler.trace`` for real-hardware runs
-  (the produced TensorBoard trace is Perfetto-loadable).
+* ``Spans`` — the host spans of a running engine.  Each ``span`` opens a
+  ``jax.profiler.TraceAnnotation`` (so a profiler trace shows it on the
+  host plane, on the device trace's clock) and notes its
+  ``time.perf_counter`` start and end in the open step's ``StepRecord``.
+  Always on: with no profiler running a span costs a TraceMe check and
+  two clock reads.  The last ``STEP_RING`` steps are kept in memory.
 * ``estimated_timeline`` — op-granular roofline timeline derived from the
   model structure + hardware spec, exported as chrome-trace JSON
   (``ui.perfetto.dev`` opens it directly).  This works on the CPU dev
@@ -13,12 +15,110 @@ Two paths, mirroring the paper's PyTorch-Profiler→Perfetto flow:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
-from typing import Dict, List, Optional
+import threading
+import time
+from collections import deque
+from typing import Deque, Dict, Iterator, List, Optional, Tuple
+
+import jax
 
 from repro.core.hardware import HardwareSpec, get_hardware
 from repro.models.config import ModelConfig
+
+STEP_RING = 4096  # engine steps whose records are kept
+STEP_SPAN = "engine.step"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+class _Compilations:
+    """Backend compilations that ended in this process (a persistent-cache
+    hit included), counted by one ``jax.monitoring`` listener.  JAX's
+    listeners are process-wide, so the count is too: a recorder reads it
+    before and after each step."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._n = 0
+        self._listening = False
+
+    def _on(self, event: str, duration: float, **kw) -> None:
+        if event == COMPILE_EVENT:
+            with self._lock:
+                self._n += 1
+
+    def count(self) -> int:
+        with self._lock:
+            if not self._listening:
+                jax.monitoring.register_event_duration_secs_listener(self._on)
+                self._listening = True
+            return self._n
+
+
+_COMPILATIONS = _Compilations()
+
+
+@dataclasses.dataclass
+class StepRecord:
+    """One engine step as the program saw it, on ``time.perf_counter``.
+
+    ``spans`` are the step's phases, ``(name, start, end)`` in the order
+    they closed; ``args`` are the step span's arguments (what the step
+    carried); ``launches`` the device launches the engine issued in it;
+    ``compiles`` the backend compilations that ended during it."""
+    index: int
+    t0: float
+    t1: float = 0.0
+    spans: List[Tuple[str, float, float]] = dataclasses.field(
+        default_factory=list)
+    args: Dict[str, int] = dataclasses.field(default_factory=dict)
+    launches: int = 0
+    compiles: int = 0
+
+
+class Spans:
+    """Per-step host spans of one serving engine, kept in a ring."""
+
+    def __init__(self):
+        self.steps: Deque[StepRecord] = deque(maxlen=STEP_RING)
+        self._open: Optional[StepRecord] = None
+        self._compiles0 = _COMPILATIONS.count()
+
+    @property
+    def compiles(self) -> int:
+        """Backend compilations since this recorder was made."""
+        return _COMPILATIONS.count() - self._compiles0
+
+    @contextlib.contextmanager
+    def step(self, index: int) -> Iterator[StepRecord]:
+        """The ``engine.step`` span; the caller fills the record's ``args``
+        and ``launches``, which the span carries as its arguments."""
+        rec = StepRecord(index, time.perf_counter())
+        c0 = _COMPILATIONS.count()
+        self._open = rec
+        try:
+            with jax.profiler.TraceAnnotation(STEP_SPAN, step=index) as ann:
+                yield rec
+                ann.set_metadata(**rec.args)
+        finally:
+            self._open = None
+            rec.t1 = time.perf_counter()
+            rec.compiles = _COMPILATIONS.count() - c0
+            self.steps.append(rec)
+
+    @contextlib.contextmanager
+    def span(self, name: str, **args) -> Iterator[None]:
+        """One phase of the open step (or a span outside any step, which
+        only the profiler sees)."""
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, **args):
+                yield
+        finally:
+            if self._open is not None:
+                self._open.spans.append((name, t0, time.perf_counter()))
 
 
 @dataclasses.dataclass
@@ -167,12 +267,3 @@ def timeline_summary(events: List[OpEvent]) -> Dict[str, float]:
         e.dur_s for e in events if e.bound == "memory") / max(total, 1e-12)
     return out
 
-
-def capture_jax_trace(path: str, fn, *args, **kwargs):
-    """Real-hardware trace via jax.profiler (TensorBoard/Perfetto format)."""
-    import jax
-
-    with jax.profiler.trace(path):
-        out = fn(*args, **kwargs)
-        jax.block_until_ready(out)
-    return out

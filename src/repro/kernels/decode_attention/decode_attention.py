@@ -176,6 +176,7 @@ def decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(q_positions.reshape(B).astype(jnp.int32), kp, q.reshape(B, Hq, D),
       kf, vf)
     return out.reshape(B, 1, Hq, D)
@@ -262,6 +263,7 @@ def paged_decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), q_positions.reshape(B).astype(jnp.int32),
       q.reshape(B, Hq, D), k_pool.reshape(N, bs, Hkv * D),
       v_pool.reshape(N, bs, Hkv * D))

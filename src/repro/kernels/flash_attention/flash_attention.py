@@ -184,5 +184,6 @@ def flash_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, qf, kf, vf)
     return out[:, :S].reshape(B, S, Hq, D)

@@ -79,4 +79,5 @@ def linear_recurrence(
         out_shape=jax.ShapeDtypeStruct((Bb, S, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
         interpret=interpret,
+        name="linear_recurrence",
     )(a, b, h0)

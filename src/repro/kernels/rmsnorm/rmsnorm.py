@@ -50,5 +50,6 @@ def rmsnorm(
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xf, scale)
     return out.reshape(orig_shape)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.energy import PowerMonitor
+from repro.core.trace import Spans
 from repro.models import cache as cache_lib
 from repro.models import model as model_lib
 from repro.models.config import ModelConfig
@@ -86,8 +87,14 @@ class Request:
     # default_factory: a shared default instance would alias any future
     # mutable sampling fields across every request that omitted params
     params: SamplingParams = dataclasses.field(default_factory=SamplingParams)
-    # filled by the engine:
+    # filled by the engine, on time.perf_counter: receipt (the HTTP
+    # handler's entry; the submission where no front end stamps it),
+    # submission, admission (slot and cursor taken), the first dispatch
+    # that carries any of its prompt, first token, finish
+    recv_time: float = 0.0
     submit_time: float = 0.0
+    admit_time: float = 0.0
+    first_chunk_time: float = 0.0
     first_token_time: float = 0.0
     finish_time: float = 0.0
     output_tokens: List[int] = dataclasses.field(default_factory=list)
@@ -306,10 +313,18 @@ class ServingEngine:
         self._next_pos = np.zeros(max_batch, np.int64)
         self._occ_samples: List[float] = []
         # device-dispatch accounting: every jitted callable is wrapped by
-        # ``_counted`` so ``_dispatches`` counts executable launches; the
-        # per-step deltas feed the dispatches_per_step percentiles
+        # ``_counted`` so ``_dispatches`` counts its launches (the per-step
+        # deltas hold the <= 2 programs/step bound); ``_launches`` counts
+        # every device call the engine makes: those programs, and the eager
+        # writes, uploads, PRNG folds and first-token draws outside them
+        # (a library call counts once).  Its per-step deltas, in the step
+        # records, feed the dispatches_per_step percentiles.
         self._dispatches = 0
         self._dispatch_samples: List[int] = []
+        self._launches = 0
+        self._admissions = 0
+        # host spans and per-step records (core/trace.py)
+        self.spans = Spans()
         # decode-side economics: device-emitted decode tokens over the
         # dispatches that carried them (speculation pushes the ratio past
         # the batch size), plus drafter accounting for the accept rate
@@ -449,12 +464,36 @@ class ServingEngine:
 
         def run(*args):
             self._dispatches += 1
+            self._launches += 1
             if self._mesh is not None:
                 with rules_lib.use_mesh(self._mesh, self._rules):
                     return fn(*args)
             return fn(*args)
 
         return run
+
+    def _put(self, x, dtype=None):
+        """Upload a host array for a device call: one launch."""
+        self._launches += 1
+        return jnp.asarray(x, dtype)
+
+    def _set_table_row(self, slot: int, tables_np: np.ndarray) -> None:
+        """Point ``slot``'s device block-table row at its pool blocks (one
+        eager write, outside any jitted program)."""
+        self._launches += 1
+        self._state["block_tables"] = (
+            self._state["block_tables"].at[slot].set(tables_np))
+
+    def _invalidate(self, slot: int) -> None:
+        """Mask ``slot``'s device row (``invalidate_slot``'s eager writes)."""
+        self._launches += 3 if "block_tables" in self._state else 2
+        self._state = invalidate_slot(self._state, slot,
+                                      garbage_block=cache_lib.GARBAGE_BLOCK)
+
+    def _decode_rows(self) -> int:
+        """Slots the step's decode half serves: live and not prefilling."""
+        return sum(req is not None and cur is None
+                   for req, cur in zip(self.slots, self._cursors))
 
     # -- public API -----------------------------------------------------------
     def submit(self, prompt: np.ndarray,
@@ -464,7 +503,7 @@ class ServingEngine:
             params = dataclasses.replace(params, top_k=self.top_k_max)
         req = Request(uid=self._uid, prompt=np.asarray(prompt, np.int32),
                       params=params)
-        req.submit_time = time.perf_counter()
+        req.submit_time = req.recv_time = time.perf_counter()
         self._uid += 1
         self.queue.append(req)
         return req.uid
@@ -485,28 +524,44 @@ class ServingEngine:
         quantum plus a separate decode step."""
         if not self.busy:
             return False
-        t0 = time.perf_counter()
-        d0 = self._dispatches
-        self._admit()
-        self._flush_resets()  # one batched row-reset dispatch per step
-        if self.unified:
-            frontier = self._pick_frontier()
-            if self.spec_k:
-                self._arm_drafts()
-            self._grow_decode_blocks()
-            self._unified_once(frontier)
-        else:
-            self._advance_chunks()
-            if self.spec_k:
-                self._arm_drafts()
-            self._grow_decode_blocks()
-            self._decode_once()
-        if self.layout == "paged":
-            self._occ_samples.append(
-                self._pool.in_use / max(self.num_blocks - 1, 1))
+        span = self.spans.span
+        d0, l0 = self._dispatches, self._launches
+        with self.spans.step(self._steps_done) as rec:
+            a0 = self._admissions
+            with span("engine.admit"):
+                self._admit()
+                self._flush_resets()  # one batched row-reset dispatch
+            rec.args["admitted"] = self._admissions - a0
+            if self.unified:
+                with span("engine.pick"):
+                    frontier = self._pick_frontier()
+                if self.spec_k:
+                    with span("engine.draft"):
+                        self._arm_drafts()
+                with span("engine.grow"):
+                    self._grow_decode_blocks()
+                rec.args.update(
+                    chunk_rows=len(frontier),
+                    chunk_tokens=sum(c for _, _, c in frontier),
+                    decode_rows=self._decode_rows())
+                self._unified_once(frontier)
+            else:
+                with span("engine.chunks"):
+                    self._advance_chunks()
+                if self.spec_k:
+                    with span("engine.draft"):
+                        self._arm_drafts()
+                with span("engine.grow"):
+                    self._grow_decode_blocks()
+                rec.args["decode_rows"] = self._decode_rows()
+                self._decode_once()
+            if self.layout == "paged":
+                self._occ_samples.append(
+                    self._pool.in_use / max(self.num_blocks - 1, 1))
+            rec.launches = self._launches - l0
         if self._steps_t0 is None:
-            self._steps_t0 = t0
-        self._steps_t1 = time.perf_counter()
+            self._steps_t0 = rec.t0
+        self._steps_t1 = rec.t1
         self._steps_done += 1
         self._dispatch_samples.append(self._dispatches - d0)
         return True
@@ -541,7 +596,7 @@ class ServingEngine:
         if self._pending_reset:
             slots, self._pending_reset = self._pending_reset, []
             self.cache = self._reset_rows(
-                self.cache, jnp.asarray(slots, jnp.int32))
+                self.cache, self._put(slots, jnp.int32))
 
     def _bucketed(self, n: int) -> int:
         b = self.prompt_bucket
@@ -670,9 +725,12 @@ class ServingEngine:
             picked_ids = {id(r) for r in picked}
             self.queue = deque(
                 r for r in self.queue if id(r) not in picked_ids)
+            now = time.perf_counter()
             for req in picked:
                 req.admit_seq = self._admit_seq
                 self._admit_seq += 1
+                req.admit_time = now
+            self._admissions += len(picked)
             slots_for = free[:len(picked)]
             if self.chunk > 0:
                 self._admit_chunked(picked, slots_for, plen)
@@ -714,11 +772,16 @@ class ServingEngine:
             padded = [padded[i] for i in keep]
             hashes = [hashes[i] for i in keep]
         n = len(reqs)
-        batch = {"tokens": jnp.asarray(np.stack(padded))}
+        now = time.perf_counter()
+        for req in reqs:
+            req.first_chunk_time = req.first_chunk_time or now
+        batch = {"tokens": self._put(np.stack(padded))}
         if self.cfg.is_encdec:
+            self._launches += 1
             batch["enc_embeds"] = jnp.zeros(
                 (n, max(plen // 2, 1), self.cfg.d_model), self._dtype)
         if self.cfg.num_vision_tokens:
+            self._launches += 1
             batch["vision_embeds"] = jnp.zeros(
                 (n, self.cfg.num_vision_tokens, self.cfg.d_model), self._dtype)
 
@@ -737,11 +800,12 @@ class ServingEngine:
                             self._pool.mark_ready(blocks[i])
             self.peak_blocks_in_use = max(self.peak_blocks_in_use,
                                           self.blocks_in_use)
-            tables = jnp.asarray(tables_np)
+            tables = self._put(tables_np)
             logits, filled = self._prefill_paged(
                 self.params, batch, self.cache, tables)
         else:
             logits, filled = self._prefill(self.params, batch)
+        self._launches += 1  # the eager merge, counted once
         self.cache = self._merge_admitted(self.cache, filled, slots_for)
 
         for r, (req, slot) in enumerate(zip(reqs, slots_for)):
@@ -840,9 +904,7 @@ class ServingEngine:
                 # arm the table row now: the unified step's packed chunk
                 # routes through state["block_tables"] (inert on the
                 # per-chunk path — tables ride as an explicit argument)
-                self._state["block_tables"] = (
-                    self._state["block_tables"].at[slot].set(
-                        jnp.asarray(tables_np)))
+                self._set_table_row(slot, tables_np)
         if self.layout == "paged":
             self.peak_blocks_in_use = max(self.peak_blocks_in_use,
                                           self.blocks_in_use)
@@ -913,30 +975,34 @@ class ServingEngine:
         threads the true length through the masked-append path (used by
         right-padded unchunked admission, so every bucket width compiles
         one executable regardless of each prompt's true span)."""
+        cur.req.first_chunk_time = (cur.req.first_chunk_time
+                                    or time.perf_counter())
         toks = cur.tokens[cur.next:cur.next + c]
         lengths = None
         if pad_to > c:
             toks = np.concatenate([toks, np.zeros(pad_to - c, np.int32)])
-            lengths = jnp.asarray([c], jnp.int32)
-        batch = {"tokens": jnp.asarray(toks[None])}
+            lengths = self._put([c], jnp.int32)
+        batch = {"tokens": self._put(toks[None])}
         start = cur.next
         nv = self.cfg.num_vision_tokens
         if self.cfg.is_encdec:
+            self._launches += 1
             batch["enc_embeds"] = jnp.zeros(
                 (1, max(cur.plen // 2, 1), self.cfg.d_model), self._dtype)
         if nv:
             # the VLM patch prefix rides with chunk 0; later chunks shift
             # past it — mirroring the unchunked prefill's concatenation
             if start == 0:
+                self._launches += 1
                 batch["vision_embeds"] = jnp.zeros(
                     (1, nv, self.cfg.d_model), self._dtype)
             else:
                 start += nv
-        slots = jnp.asarray([slot], jnp.int32)
+        slots = self._put([slot], jnp.int32)
         if self.layout == "paged":
             logits, self.cache = self._chunk_paged(
                 self.params, batch, start, slots, self.cache,
-                jnp.asarray(cur.tables_np[None]), lengths)
+                self._put(cur.tables_np[None]), lengths)
         else:
             logits, self.cache = self._chunk_contig(
                 self.params, batch, start, slots, self.cache, lengths)
@@ -976,41 +1042,49 @@ class ServingEngine:
         if not frontier:
             self._decode_once()
             return
-        W = self._chunk_width
-        tokens = np.zeros((self.max_batch, W), np.int32)
-        starts = np.zeros(self.max_batch, np.int32)
-        lens = np.zeros(self.max_batch, np.int32)
-        for slot, cur, c in frontier:
-            tokens[slot, :c] = cur.tokens[cur.next:cur.next + c]
-            starts[slot] = cur.next
-            lens[slot] = c
-        chunk = {"tokens": jnp.asarray(tokens), "start": jnp.asarray(starts),
-                 "length": jnp.asarray(lens)}
-        self._state, self.cache, out, chunk_logits = self._unified(
-            self.params, self._state, chunk, self.cache)
-        # the single packed host<->device sync of the step
-        out_np, logits_np = jax.device_get((out, chunk_logits))
-        for slot, cur, c in frontier:
-            if self._cursors[slot] is not cur:
-                # the slot was preempted between frontier pick and dispatch
-                # (_grow_decode_blocks ran dry): its table row was pointed
-                # at the garbage block before the launch, so the chunk's
-                # writes landed in trash — drop the stale advance
-                continue
-            cur.next += c
-            while cur.pending_ready and cur.pending_ready[0][0] <= cur.next:
-                self._pool.mark_ready(cur.pending_ready.pop(0)[1])
-            if cur.next == cur.plen:  # final chunk landed: decode-eligible
-                self._prefill_order.remove(slot)
-                self._cursors[slot] = None
-                if cur.resume_n > 0:
-                    self._resume_decoding(cur.req, slot, cur.plen,
-                                          cur.resume_n, cur.tables_np)
-                else:
-                    self._start_decoding(cur.req, slot, cur.plen,
-                                         logits_np[slot:slot + 1],
-                                         cur.tables_np)
-        self._process_out(out_np)
+        span = self.spans.span
+        with span("engine.launch"):
+            now = time.perf_counter()
+            W = self._chunk_width
+            tokens = np.zeros((self.max_batch, W), np.int32)
+            starts = np.zeros(self.max_batch, np.int32)
+            lens = np.zeros(self.max_batch, np.int32)
+            for slot, cur, c in frontier:
+                tokens[slot, :c] = cur.tokens[cur.next:cur.next + c]
+                starts[slot] = cur.next
+                lens[slot] = c
+                cur.req.first_chunk_time = cur.req.first_chunk_time or now
+            chunk = {"tokens": self._put(tokens), "start": self._put(starts),
+                     "length": self._put(lens)}
+            self._state, self.cache, out, chunk_logits = self._unified(
+                self.params, self._state, chunk, self.cache)
+        with span("engine.sync"):
+            # the single packed host<->device sync of the step
+            out_np, logits_np = jax.device_get((out, chunk_logits))
+        with span("engine.emit"):
+            for slot, cur, c in frontier:
+                if self._cursors[slot] is not cur:
+                    # the slot was preempted between frontier pick and
+                    # dispatch (_grow_decode_blocks ran dry): its table row
+                    # was pointed at the garbage block before the launch,
+                    # so the chunk's writes landed in trash — drop the
+                    # stale advance
+                    continue
+                cur.next += c
+                while (cur.pending_ready
+                       and cur.pending_ready[0][0] <= cur.next):
+                    self._pool.mark_ready(cur.pending_ready.pop(0)[1])
+                if cur.next == cur.plen:  # final chunk: decode-eligible
+                    self._prefill_order.remove(slot)
+                    self._cursors[slot] = None
+                    if cur.resume_n > 0:
+                        self._resume_decoding(cur.req, slot, cur.plen,
+                                              cur.resume_n, cur.tables_np)
+                    else:
+                        self._start_decoding(cur.req, slot, cur.plen,
+                                             logits_np[slot:slot + 1],
+                                             cur.tables_np)
+            self._process_out(out_np)
 
     # -- speculative decoding ----------------------------------------------------
     def _arm_drafts(self) -> None:
@@ -1046,8 +1120,8 @@ class ServingEngine:
             draft_np[slot, :len(d)] = d
             self._draft_len_host[slot] = len(d)
             self._drafted_tokens += len(d)
-        self._state["draft"] = jnp.asarray(draft_np)
-        self._state["draft_len"] = jnp.asarray(
+        self._state["draft"] = self._put(draft_np)
+        self._state["draft_len"] = self._put(
             self._draft_len_host.astype(np.int32))
 
     def _process_spec_out(self, out_np: np.ndarray) -> None:
@@ -1101,6 +1175,7 @@ class ServingEngine:
         extra = blocks[keep:]
         self._slot_blocks[slot] = blocks[:keep]
         self._pool.free(extra)
+        self._launches += 1
         self._state["block_tables"] = (
             self._state["block_tables"].at[slot, keep:keep + len(extra)].set(
                 cache_lib.GARBAGE_BLOCK))
@@ -1139,6 +1214,7 @@ class ServingEngine:
                 blk = self._pool.allocate(1)[0]
                 self._slot_blocks[slot].append(blk)
                 idx = len(self._slot_blocks[slot]) - 1
+                self._launches += 1
                 self._state["block_tables"] = (
                     self._state["block_tables"].at[slot, idx].set(blk))
             self.peak_blocks_in_use = max(self.peak_blocks_in_use,
@@ -1168,8 +1244,7 @@ class ServingEngine:
             self._cursors[slot] = None
             self._prefill_order.remove(slot)
         self.slots[slot] = None
-        self._state = invalidate_slot(self._state, slot,
-                                      garbage_block=cache_lib.GARBAGE_BLOCK)
+        self._invalidate(slot)
         if self._slot_blocks[slot]:
             self._pool.free(self._slot_blocks[slot])
             self._slot_blocks[slot] = []
@@ -1200,6 +1275,7 @@ class ServingEngine:
         if nb - self._peek_hit(req, plen) > self._pool.available:
             return False
         self._preempted.pop(0)
+        self._admissions += 1
         slot = free[0]
         toks = self._padded_prompt(req, plen)[:span]
         if n > 1:
@@ -1234,9 +1310,7 @@ class ServingEngine:
             self._prefill_order.append(slot)
             # arm the table row for the unified step's packed chunk (the
             # per-chunk path passes tables explicitly; harmless there)
-            self._state["block_tables"] = (
-                self._state["block_tables"].at[slot].set(
-                    jnp.asarray(tables_np)))
+            self._set_table_row(slot, tables_np)
         else:
             self._flush_resets()  # the replay chunk runs right now
             logits = self._run_chunk(slot, cur, total - start)
@@ -1260,6 +1334,7 @@ class ServingEngine:
         is byte-identical to an unpreempted run."""
         rk = jax.random.fold_in(self._base_key, req.uid)
         key = self._advance_chain(jax.random.fold_in(rk, 1), n - 1)
+        self._launches += 2  # the two folds
         remaining = req.params.max_new_tokens - n
         # a live preempted request always has budget and headroom left
         # (finish flags are processed before preemption can run); guard
@@ -1269,9 +1344,7 @@ class ServingEngine:
             slot, token=req.output_tokens[-1], position=position,
             remaining=remaining, params=req.params, active=active, key=key)
         if tables_np is not None:
-            self._state["block_tables"] = (
-                self._state["block_tables"].at[slot].set(
-                    jnp.asarray(tables_np)))
+            self._set_table_row(slot, tables_np)
         self._next_pos[slot] = position
         if not active:
             self._finish(slot)
@@ -1283,6 +1356,7 @@ class ServingEngine:
         Shared by unchunked admission and final-chunk completion."""
         rk = jax.random.fold_in(self._base_key, req.uid)
         first = int(sample(logits, req.params, jax.random.fold_in(rk, 0))[0])
+        self._launches += 4  # three folds and the draw
         req.first_token_time = time.perf_counter()
         req.output_tokens.append(first)
         self._count_token(req)
@@ -1299,16 +1373,16 @@ class ServingEngine:
             key=jax.random.fold_in(rk, 1))
         self._next_pos[slot] = plen
         if self.layout == "paged" and tables_np is not None:
-            self._state["block_tables"] = (
-                self._state["block_tables"].at[slot].set(
-                    jnp.asarray(tables_np)))
+            self._set_table_row(slot, tables_np)
         if done:
             self._finish(slot)
 
     def _write_slot_state(self, slot: int, *, token: int, position: int,
                           remaining: int, params: SamplingParams,
                           active: bool, key) -> None:
-        """Admission-time write of one slot's device state (lazy device ops)."""
+        """Admission-time write of one slot's device state (eight eager
+        device writes)."""
+        self._launches += 8
         s = self._state
         s["tokens"] = s["tokens"].at[slot, 0].set(token)
         s["positions"] = s["positions"].at[slot].set(position)
@@ -1384,12 +1458,16 @@ class ServingEngine:
     def _decode_once(self) -> None:
         # prefilling slots (open cursor) are not decode-eligible: their
         # first token is sampled only once the final chunk lands
-        if not any(req is not None and cur is None
-                   for req, cur in zip(self.slots, self._cursors)):
+        if not self._decode_rows():
             return
-        self._state, self.cache, out = self._step(
-            self.params, self._state, self.cache)
-        self._process_out(np.asarray(out))  # single host sync
+        span = self.spans.span
+        with span("engine.launch"):
+            self._state, self.cache, out = self._step(
+                self.params, self._state, self.cache)
+        with span("engine.sync"):
+            out_np = np.asarray(out)  # single host sync
+        with span("engine.emit"):
+            self._process_out(out_np)
 
     def _process_out(self, out_np: np.ndarray) -> None:
         """Route one step's packed device sync to the right parser: the
@@ -1454,8 +1532,7 @@ class ServingEngine:
         # decode finishes; admission-time finishes need it explicitly) and
         # point the paged table row at the garbage block so idle writes
         # land in trash
-        self._state = invalidate_slot(self._state, slot,
-                                      garbage_block=cache_lib.GARBAGE_BLOCK)
+        self._invalidate(slot)
         if self.layout == "paged" and self._slot_blocks[slot]:
             # return the slot's blocks: shared blocks decref and park on
             # the evictable LRU; private ones hit the free stack
@@ -1600,7 +1677,6 @@ class ServingEngine:
             "truncated": sum(1 for r in self.finished if r.truncated),
             "output_tokens": out_tokens,
             "tokens_per_sec": out_tokens / span,
-            "decode_tokens_per_sec": out_tokens / span,
             "prefill_tokens_per_sec": prefill_tokens / span,
             "tokens_per_dispatch": (
                 self._decode_tokens / max(self._decode_dispatches, 1)),
@@ -1611,6 +1687,19 @@ class ServingEngine:
         for name, xs in (("ttft", ttfts), ("tpot", tpots), ("ttlt", ttlts)):
             for q in (50, 95, 99):
                 summary[f"{name}_p{q}_ms"] = _percentile(xs, q) * 1e3
+        # where a request waited: for the engine thread to take its
+        # submission (behind the running step), for a slot, and behind the
+        # head prefill cursor
+        for name, xs in (
+                ("submit_wait", [r.submit_time - r.recv_time
+                                 for r in self.finished]),
+                ("queue_wait", [r.admit_time - r.submit_time
+                                for r in self.finished]),
+                ("prefill_wait", [r.first_chunk_time - r.admit_time
+                                  for r in self.finished])):
+            for q in (50, 95):
+                summary[f"{name}_p{q}_ms"] = _percentile(xs, q) * 1e3
+        summary["compilations"] = self.spans.compiles
         summary["kv_bytes_peak"] = self.kv_bytes_in_use(peak=True)
         summary["kv_bytes_worst_case"] = self.kv_bytes_worst_case
         if self._mesh is not None:
@@ -1623,10 +1712,10 @@ class ServingEngine:
         if self._steps_done:
             wall = max(self._steps_t1 - (self._steps_t0 or 0.0), 1e-9)
             summary["steps_per_sec"] = self._steps_done / wall
-            summary["dispatches_per_step_p50"] = _percentile(
-                self._dispatch_samples, 50)
-            summary["dispatches_per_step_p95"] = _percentile(
-                self._dispatch_samples, 95)
+            # every device launch, over the steps the span ring still holds
+            launches = [r.launches for r in self.spans.steps]
+            summary["dispatches_per_step_p50"] = _percentile(launches, 50)
+            summary["dispatches_per_step_p95"] = _percentile(launches, 95)
         if self.spec_k:
             summary["drafted_tokens"] = self._drafted_tokens
             summary["accepted_tokens"] = self._accepted_tokens

@@ -93,6 +93,7 @@ class _Submission:
     prompt: np.ndarray
     params: SamplingParams
     stream: _Stream
+    recv_time: float  # the handler's entry, on time.perf_counter
 
 
 class EngineServer:
@@ -155,6 +156,7 @@ class EngineServer:
         with self._lock:
             uid = self.engine.submit(sub.prompt, sub.params)
             req = self.engine.queue[-1]
+            req.recv_time = sub.recv_time
         sub.stream.uid = uid
         self._reqs[uid] = req
         self._streams[uid] = sub.stream
@@ -224,6 +226,7 @@ class EngineServer:
             dumps=lambda o: json.dumps(o, default=float))
 
     async def handle_completions(self, request: "web.Request"):
+        recv_time = time.perf_counter()
         try:
             body = await request.json()
         except Exception:
@@ -245,7 +248,7 @@ class EngineServer:
 
         self.requests_received += 1
         handle = _Stream(asyncio.get_running_loop())
-        self._subq.put(_Submission(prompt, params, handle))
+        self._subq.put(_Submission(prompt, params, handle, recv_time))
         _, uid, _submit_time = await handle.q.get()  # ("begin", uid, t)
         cid = f"cmpl-{uid}"
         created = int(time.time())

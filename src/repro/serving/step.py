@@ -10,6 +10,12 @@ splitting, position/budget bookkeeping and finish detection.  The host feeds
 it a small ``state`` dict of per-slot device arrays and reads back a single
 packed (3, B) int32 array per step — the only host<->device sync in the
 steady-state decode loop.
+
+The fused steps name their parts with ``jax.named_scope`` — ``chunk_pass``
+(the packed prefill chunk), ``decode`` (the model forward of the decode
+half or the speculative verify) and ``sample`` (sampling, acceptance and
+finish detection) — so that a device trace can put each op's time down to
+one of them.  Scopes change HLO metadata only, never the computation.
 """
 
 from __future__ import annotations
@@ -152,29 +158,32 @@ def _decode_sample_body(cfg: ModelConfig, max_len: int, k_max: int,
     ``make_engine_step`` (identical math, so fused and split paths emit
     byte-identical streams)."""
     active = state["active"]
-    logits, new_cache = model_lib.decode_step(
-        cfg, params, state["tokens"], state["positions"], cache,
-        block_tables=state.get("block_tables"), update_mask=active)
-    split = jax.vmap(jax.random.split)(state["keys"])   # (B, 2, 2)
-    tok = sample_slots_keyed(logits, state["temperature"], state["top_k"],
-                             split[:, 0], k_max=k_max)
+    with jax.named_scope("decode"):
+        logits, new_cache = model_lib.decode_step(
+            cfg, params, state["tokens"], state["positions"], cache,
+            block_tables=state.get("block_tables"), update_mask=active)
+    with jax.named_scope("sample"):
+        split = jax.vmap(jax.random.split)(state["keys"])   # (B, 2, 2)
+        tok = sample_slots_keyed(logits, state["temperature"],
+                                 state["top_k"], split[:, 0], k_max=k_max)
 
-    act_i = active.astype(jnp.int32)
-    tok = jnp.where(active, tok, state["tokens"][:, 0])
-    positions = state["positions"] + act_i
-    remaining = state["remaining"] - act_i
-    hit_eos = (state["eos"] >= 0) & (tok == state["eos"])
-    done = active & (hit_eos | (remaining <= 0) | (positions >= max_len - 1))
+        act_i = active.astype(jnp.int32)
+        tok = jnp.where(active, tok, state["tokens"][:, 0])
+        positions = state["positions"] + act_i
+        remaining = state["remaining"] - act_i
+        hit_eos = (state["eos"] >= 0) & (tok == state["eos"])
+        done = active & (hit_eos | (remaining <= 0)
+                         | (positions >= max_len - 1))
 
-    new_state = dict(state)  # block_tables etc. pass through untouched
-    new_state.update(
-        tokens=tok[:, None],
-        positions=positions,
-        active=active & ~done,
-        remaining=remaining,
-        keys=jnp.where(active[:, None], split[:, 1], state["keys"]),
-    )
-    out = jnp.stack([tok, done.astype(jnp.int32), act_i])
+        new_state = dict(state)  # block_tables etc. pass through untouched
+        new_state.update(
+            tokens=tok[:, None],
+            positions=positions,
+            active=active & ~done,
+            remaining=remaining,
+            keys=jnp.where(active[:, None], split[:, 1], state["keys"]),
+        )
+        out = jnp.stack([tok, done.astype(jnp.int32), act_i])
     return new_state, new_cache, out
 
 
@@ -197,31 +206,33 @@ def _spec_verify_body(cfg: ModelConfig, max_len: int, k_max: int, spec_k: int,
     1) int32 sync: emitted tokens | emission mask | finished flag.
     """
     active = state["active"]
-    window = jnp.concatenate([state["tokens"], state["draft"]], axis=1)
-    lengths = jnp.where(active, state["draft_len"] + 1, 0)
-    logits, new_cache = model_lib.prefill_chunk(
-        cfg, params, {"tokens": window}, cache, state["positions"],
-        block_tables=state.get("block_tables"), lengths=lengths,
-        overwrite_from=state["positions"], all_logits=True)
-    res = verify_slots_keyed(
-        logits, state["draft"], state["draft_len"], state["temperature"],
-        state["top_k"], state["keys"], active=active,
-        tokens0=state["tokens"][:, 0], positions=state["positions"],
-        remaining=state["remaining"], eos=state["eos"],
-        max_len=max_len, k_max=k_max)
-    new_state = dict(state)  # block_tables / draft ride through untouched
-    new_state.update(
-        tokens=res["last_token"][:, None],
-        positions=res["positions"],
-        active=res["active"],
-        remaining=res["remaining"],
-        keys=res["keys"],
-    )
-    out = jnp.concatenate([
-        res["tokens"],
-        res["emit"].astype(jnp.int32),
-        res["done"].astype(jnp.int32)[:, None],
-    ], axis=1)
+    with jax.named_scope("decode"):
+        window = jnp.concatenate([state["tokens"], state["draft"]], axis=1)
+        lengths = jnp.where(active, state["draft_len"] + 1, 0)
+        logits, new_cache = model_lib.prefill_chunk(
+            cfg, params, {"tokens": window}, cache, state["positions"],
+            block_tables=state.get("block_tables"), lengths=lengths,
+            overwrite_from=state["positions"], all_logits=True)
+    with jax.named_scope("sample"):
+        res = verify_slots_keyed(
+            logits, state["draft"], state["draft_len"],
+            state["temperature"], state["top_k"], state["keys"],
+            active=active, tokens0=state["tokens"][:, 0],
+            positions=state["positions"], remaining=state["remaining"],
+            eos=state["eos"], max_len=max_len, k_max=k_max)
+        new_state = dict(state)  # block_tables / draft ride through
+        new_state.update(
+            tokens=res["last_token"][:, None],
+            positions=res["positions"],
+            active=res["active"],
+            remaining=res["remaining"],
+            keys=res["keys"],
+        )
+        out = jnp.concatenate([
+            res["tokens"],
+            res["emit"].astype(jnp.int32),
+            res["done"].astype(jnp.int32)[:, None],
+        ], axis=1)
     return new_state, new_cache, out
 
 
@@ -273,9 +284,11 @@ def make_engine_step(cfg: ModelConfig, max_len: int,
 
     def step(params, state: Dict[str, jax.Array], chunk: Dict[str, jax.Array],
              cache):
-        chunk_logits, cache = model_lib.prefill_chunk(
-            cfg, params, {"tokens": chunk["tokens"]}, cache, chunk["start"],
-            block_tables=state.get("block_tables"), lengths=chunk["length"])
+        with jax.named_scope("chunk_pass"):
+            chunk_logits, cache = model_lib.prefill_chunk(
+                cfg, params, {"tokens": chunk["tokens"]}, cache,
+                chunk["start"], block_tables=state.get("block_tables"),
+                lengths=chunk["length"])
         if spec_k > 0:
             new_state, new_cache, out = _spec_verify_body(
                 cfg, max_len, k_max, spec_k, params, state, cache)

@@ -190,11 +190,31 @@ def test_models_and_metrics_endpoints(server):
     # engine ledger + server counters in one scrape
     assert metrics["requests"] >= 1
     for key in ("ttft_ms", "tpot_ms", "ttlt_ms", "tokens_per_sec",
+                "submit_wait_p50_ms", "queue_wait_p95_ms",
+                "prefill_wait_p50_ms", "compilations",
                 "server_requests_received", "server_chunks_streamed",
                 "server_in_flight", "server_uptime_s"):
         assert key in metrics, key
     assert metrics["server_requests_received"] >= 1
     assert metrics["server_chunks_streamed"] >= 3
+
+
+def test_receipt_is_stamped_before_submission(server):
+    """The handler stamps a request's receipt; the engine thread takes the
+    submission later, between steps."""
+    handle, _, _ = server
+
+    async def go():
+        async with aiohttp.ClientSession() as s:
+            return await stream_completion(s, handle.url, [5, 3, 5],
+                                           max_tokens=3)
+
+    rec = asyncio.run(go())
+    assert not rec.error
+    req = max(handle.server.engine.finished, key=lambda r: r.uid)
+    assert rec.send_time <= req.recv_time <= req.submit_time
+    assert req.submit_time <= req.admit_time <= req.first_chunk_time
+    assert req.first_chunk_time <= req.first_token_time <= req.finish_time
 
 
 def test_concurrent_streams_complete(server):

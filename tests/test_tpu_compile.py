@@ -13,6 +13,7 @@ several test workers only the worker given this file may load it.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.hardware import TPU_V5E, hardware_for_device
 from repro.kernels.decode_attention import ops as decode_ops
 from repro.kernels.flash_attention import ops as flash_ops
+from repro.kernels.rmsnorm import ops as rmsnorm_ops
 
 B, H, D = 8, 16, 64          # qwen1.5-0.5b: 16 MHA heads of 64
 
@@ -58,6 +60,12 @@ def _compiled_hlo(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _named(hlo, kernel):
+    """The kernel's op carries its ``pallas_call`` name, which the
+    benchmark's trace reduction finds it by."""
+    return re.search(rf"%{kernel}(\.\d+)? = ", hlo) is not None
+
+
 def _flash(q, k, v, qp, kp):
     return flash_ops.flash_attention(q, k, v, q_positions=qp, k_positions=kp,
                                      causal=True)
@@ -76,6 +84,7 @@ def test_flash_attention_compiles_for_v5e(one_chip, S, T):
                         ((B, T, H, D), BF), ((B, T, H, D), BF),
                         ((B, S), I32), ((B, T), I32))
     assert "tpu_custom_call" in hlo
+    assert _named(hlo, "flash_attention")
 
 
 def test_decode_attention_compiles_for_v5e(one_chip):
@@ -86,6 +95,7 @@ def test_decode_attention_compiles_for_v5e(one_chip):
         one_chip, ((B, 1, H, D), BF), ((B, L, H, D), BF),
         ((B, L, H, D), BF), ((B, 1), I32), ((B, L), I32))
     assert "tpu_custom_call" in hlo
+    assert _named(hlo, "decode_attention")
 
 
 def test_paged_decode_attention_compiles_for_v5e(one_chip):
@@ -97,6 +107,13 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip):
         one_chip, ((B, 1, H, D), BF), ((N, bs, H, D), BF),
         ((N, bs, H, D), BF), ((B, nb), I32), ((B, 1), I32))
     assert "tpu_custom_call" in hlo
+    assert _named(hlo, "paged_decode_attention")
+
+
+def test_rmsnorm_carries_its_name(one_chip):
+    hlo = _compiled_hlo(lambda x, s: rmsnorm_ops.rmsnorm(x, s), one_chip,
+                        ((B, 256, 1024), BF), ((1024,), BF))
+    assert _named(hlo, "rmsnorm")
 
 
 def test_described_v5e_has_a_hardware_spec(topo):
