@@ -3,11 +3,12 @@
 
 TPU-native design:
   * heads ride in the lane dimension: the cache is viewed as (B, L, H*D)
-    and a pool as (N, bs, H*D), so every block's last two dimensions are
-    (rows, H*D), whole in the lanes, as the TPU compiler requires.  The
-    kernel walks the kv heads with static lane slices.  On the TPU the
-    reshape from the model's (..., H, D) layout is a relayout copy of the
-    whole cache or pool per call (tiles of the last two dims differ).
+    and a pool is stored as (layers, N, bs, H*D), so every block's last two
+    dimensions are (rows, H*D), whole in the lanes, as the TPU compiler
+    requires.  The kernel walks the kv heads with static lane slices.  On
+    the TPU the contiguous cache's reshape from the model's (B, L, H, D)
+    layout is a relayout copy of the whole cache per call (tiles of the
+    last two dims differ); the pool is stored lane-dense and read as is.
   * GQA grouping is exploited for MXU utilization: the G query heads that
     share one kv head are processed together as a (G, D) LHS, so the score
     matmul is (G, D) x (D, bk) instead of G separate vector products.
@@ -20,11 +21,12 @@ TPU-native design:
     and a ragged cache length is padded to whole blocks at position -1.
 
 ``paged_decode_attention`` reuses the same online-softmax body but reads
-K/V straight out of a global block pool: the per-sequence block table is a
-scalar-prefetch operand (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec
-index map resolves logical kv-block ``ki`` of sequence ``b`` to physical
-pool block ``table[b, ki]`` before the DMA is issued — no gather/copy of
-the cache ever materializes.  Key positions are synthesized from the grid
+K/V straight out of a global block pool stacked over layers: the layer
+index and the per-sequence block table are scalar-prefetch operands
+(``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index map resolves
+logical kv-block ``ki`` of sequence ``b`` to pool block ``(layer,
+table[b, ki])`` before the DMA is issued — no slice, gather or copy of the
+pool ever materializes.  Key positions are synthesized from the grid
 (``ki * block_size + iota``): gathered index == absolute position, so
 causal masking hides the unwritten tail and garbage-block table entries.
 """
@@ -183,6 +185,7 @@ def decode_attention(
 
 
 def _paged_kernel(
+    layer_ref,                  # scalar-prefetch: (1,) int32 pool layer
     bt_ref,                     # scalar-prefetch: (B, nb) int32 block table
     q_pos_ref,                  # scalar-prefetch: (B,) int32
     q_ref,                      # (1, Hq, D)
@@ -195,7 +198,7 @@ def _paged_kernel(
     block_size: int,
     **body,
 ):
-    del bt_ref  # consumed by the index maps
+    del layer_ref, bt_ref  # consumed by the index maps
     b, ki = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -224,10 +227,11 @@ def _paged_kernel(
 )
 def paged_decode_attention(
     q: jax.Array,              # (B, 1, Hq, D)
-    k_pool: jax.Array,         # (N, bs, Hkv, D) global block pool
-    v_pool: jax.Array,         # (N, bs, Hkv, D)
+    k_pool: jax.Array,         # (layers, N, bs, Hkv*D) stacked block pools
+    v_pool: jax.Array,         # (layers, N, bs, Hkv*D)
     block_tables: jax.Array,   # (B, nb) int32 pool indices
     q_positions: jax.Array,    # (B, 1) int32
+    layer: jax.Array,          # int32 scalar: the pools' layer to read
     *,
     window: int = 0,
     softcap: float = 0.0,
@@ -236,7 +240,8 @@ def paged_decode_attention(
     B, S, Hq, D = q.shape
     if S != 1:
         raise ValueError(f"decode kernel is single-token, got {S} queries")
-    N, bs, Hkv, _ = k_pool.shape
+    _, _, bs, HD = k_pool.shape
+    Hkv = HD // D
     nb = block_tables.shape[1]
 
     kernel = functools.partial(
@@ -244,16 +249,17 @@ def paged_decode_attention(
         num_kv_heads=Hkv, group=Hq // Hkv, head_dim=D, softcap=softcap,
         scale=1.0 / math.sqrt(D),
     )
-    kv_map = lambda b, ki, bt, qp: (bt[b, ki], 0, 0)
+    kv_map = lambda b, ki, lyr, bt, qp: (lyr[0], bt[b, ki], 0, 0)
+    q_map = lambda b, ki, lyr, bt, qp: (b, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, nb),
         in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, ki, bt, qp: (b, 0, 0)),
-            pl.BlockSpec((1, bs, Hkv * D), kv_map),
-            pl.BlockSpec((1, bs, Hkv * D), kv_map),
+            pl.BlockSpec((1, Hq, D), q_map),
+            pl.BlockSpec((None, 1, bs, HD), kv_map),
+            pl.BlockSpec((None, 1, bs, HD), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, Hq, D), lambda b, ki, bt, qp: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, D), q_map),
         scratch_shapes=_scratch(Hq, D),
     )
     out = pl.pallas_call(
@@ -264,7 +270,7 @@ def paged_decode_attention(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables.astype(jnp.int32), q_positions.reshape(B).astype(jnp.int32),
-      q.reshape(B, Hq, D), k_pool.reshape(N, bs, Hkv * D),
-      v_pool.reshape(N, bs, Hkv * D))
+    )(jnp.asarray(layer, jnp.int32).reshape(1), block_tables.astype(jnp.int32),
+      q_positions.reshape(B).astype(jnp.int32), q.reshape(B, Hq, D),
+      k_pool, v_pool)
     return out.reshape(B, 1, Hq, D)

@@ -19,10 +19,11 @@ def decode_attention(q, k_cache, v_cache, *, q_positions, k_positions,
 
 
 def paged_decode_attention(q, k_pool, v_pool, *, block_tables, q_positions,
-                           window=0, softcap=0.0, interpret=False):
-    """Paged variant: kv tiles DMA'd straight from the pool via the
-    scalar-prefetched block table (tile size == pool block size)."""
+                           layer, window=0, softcap=0.0, interpret=False):
+    """Paged variant: kv tiles DMA'd straight from layer ``layer`` of the
+    stacked pools (layers, N, bs, Hkv*D) via the scalar-prefetched block
+    table (tile size == pool block size)."""
     return _pallas_paged(
-        q, k_pool, v_pool, block_tables, q_positions,
+        q, k_pool, v_pool, block_tables, q_positions, layer,
         window=window, softcap=softcap, interpret=interpret,
     )

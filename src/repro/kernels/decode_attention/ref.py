@@ -6,10 +6,11 @@ are unfilled and masked; window masking uses absolute positions so ring
 buffers work unchanged.
 
 ``paged_decode_attention``: same math over a paged cache — K/V are gathered
-from a global block pool through a per-sequence block table, and key
-positions are synthesized (gathered index j == absolute position j), so
-causal masking hides both the unwritten tail of the last block and any
-garbage-block table entries (their positions all exceed the query's).
+from one layer of the stacked block pools (layers, N, bs, Hkv*D) through a
+per-sequence block table, and key positions are synthesized (gathered
+index j == absolute position j), so causal masking hides both the
+unwritten tail of the last block and any garbage-block table entries
+(their positions all exceed the query's).
 """
 
 from __future__ import annotations
@@ -52,19 +53,22 @@ def decode_attention(
 
 def paged_decode_attention(
     q: jax.Array,              # (B, 1, Hq, D)
-    k_pool: jax.Array,         # (N, bs, Hkv, D) global block pool
-    v_pool: jax.Array,         # (N, bs, Hkv, D)
+    k_pool: jax.Array,         # (layers, N, bs, Hkv*D) stacked block pools
+    v_pool: jax.Array,         # (layers, N, bs, Hkv*D)
     *,
     block_tables: jax.Array,   # (B, max_blocks) int32 pool indices
     q_positions: jax.Array,    # (B, 1)
+    layer,                     # int32 scalar: the pools' layer to read
     window: int = 0,
     softcap: float = 0.0,
 ) -> jax.Array:
     B, nb = block_tables.shape
-    bs = k_pool.shape[1]
+    D = q.shape[-1]
+    bs = k_pool.shape[2]
     L = nb * bs
-    k = k_pool[block_tables].reshape(B, L, *k_pool.shape[2:])
-    v = v_pool[block_tables].reshape(B, L, *v_pool.shape[2:])
+    shape = (B, L, k_pool.shape[3] // D, D)
+    k = k_pool[layer, block_tables].reshape(shape)
+    v = v_pool[layer, block_tables].reshape(shape)
     k_positions = jnp.broadcast_to(
         jnp.arange(L, dtype=jnp.int32)[None], (B, L))
     return decode_attention(

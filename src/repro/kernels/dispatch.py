@@ -26,6 +26,7 @@ import contextlib
 import threading
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import rules
@@ -70,28 +71,41 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _per_device(kernel, q, k, v, rows, *, kv_rows: bool = True):
+def _per_device(kernel, q, k, v, rows, *, layer=None):
     """``kernel(q, k, v, *rows)`` once per device of the ambient mesh.
 
     ``q`` is (B, S, Hq, D) and ``k``/``v`` carry their kv heads at dim 2;
     ``rows`` are per-sequence (B, ·) operands.  Batch rows and heads split
     as the rules shard ``batch`` and ``act_heads``; the kv heads follow the
     query heads (so a device's query heads find their GQA kv head locally),
-    and stay whole when they do not divide.  ``kv_rows=False`` keeps k/v
-    whole along dim 0 (a paged pool is indexed by block, not by row)."""
+    and stay whole when they do not divide.
+
+    With ``layer`` given, ``k``/``v`` are paged pools stacked over layers,
+    (layers, N, bs, Hkv*D), and the call is ``kernel(q, k, v, *rows,
+    layer)``: a pool is indexed by block, not by row, so only its lanes
+    split, by whole kv heads as above."""
+    if layer is not None:
+        rows = (*rows, jnp.asarray(layer, jnp.int32).reshape(1))
     mesh = rules.current_mesh()
     if mesh is None:
         return kernel(q, k, v, *rows)
     logical = ("batch", None, "act_heads", None)
     batch, _, heads, _ = rules.logical_to_pspec(logical, dims=q.shape,
                                                 mesh=mesh)
-    if rules.logical_to_pspec(logical, dims=k.shape, mesh=mesh)[2] != heads:
+    n_kv = k.shape[-1] // q.shape[-1] if layer is not None else k.shape[2]
+    if rules.logical_to_pspec(logical, dims=(1, 1, n_kv, 1),
+                              mesh=mesh)[2] != heads:
         heads = None
     q_spec = P(batch, None, heads, None)
-    kv_spec = P(batch if kv_rows else None, None, heads, None)
+    row_specs = (P(batch, None),) * len(rows)
+    if layer is None:
+        kv_spec = q_spec
+    else:
+        kv_spec = P(None, None, None, heads)
+        row_specs = row_specs[:-1] + (P(None),)
     return jax.shard_map(
         kernel, mesh=mesh,
-        in_specs=(q_spec, kv_spec, kv_spec) + (P(batch, None),) * len(rows),
+        in_specs=(q_spec, kv_spec, kv_spec) + row_specs,
         out_specs=q_spec, check_vma=False)(q, k, v, *rows)
 
 
@@ -141,19 +155,20 @@ def decode_attention(q, k_cache, v_cache, *, q_positions, k_positions,
 
 
 def paged_decode_attention(q, k_pool, v_pool, *, block_tables, q_positions,
-                           window=0, softcap=0.0):
-    """Decode attention over a paged (block-pool) KV cache."""
+                           layer, window=0, softcap=0.0):
+    """Decode attention over layer ``layer`` of paged (block-pool) KV
+    caches stacked over layers, (layers, N, bs, Hkv*D)."""
     if _use_pallas():
         from repro.kernels.decode_attention import ops
         return _per_device(
-            lambda q, k, v, bt, qp: ops.paged_decode_attention(
-                q, k, v, block_tables=bt, q_positions=qp, window=window,
-                softcap=softcap, interpret=_interpret()),
-            q, k_pool, v_pool, (block_tables, q_positions), kv_rows=False)
+            lambda q, k, v, bt, qp, lyr: ops.paged_decode_attention(
+                q, k, v, block_tables=bt, q_positions=qp, layer=lyr,
+                window=window, softcap=softcap, interpret=_interpret()),
+            q, k_pool, v_pool, (block_tables, q_positions), layer=layer)
     from repro.kernels.decode_attention import ref
     return ref.paged_decode_attention(
         q, k_pool, v_pool, block_tables=block_tables,
-        q_positions=q_positions, window=window, softcap=softcap,
+        q_positions=q_positions, layer=layer, window=window, softcap=softcap,
     )
 
 

@@ -127,13 +127,14 @@ def apply_attention_prefill(
     *,
     window: int = 0,
     block_tables: Optional[jax.Array] = None,
+    layer=0,
 ) -> Tuple[jax.Array, Dict]:
     """Causal attention over the prompt; returns output + filled KV cache.
 
     The attention math is layout-independent (the prompt is self-contained);
     only the cache write differs: paged entries (``kp`` in the dict) scatter
-    K/V into pool blocks through ``block_tables``, contiguous/ring entries
-    take the dense fill.
+    K/V into pool blocks of ``layer`` (the index into the stacked pools)
+    through ``block_tables``, contiguous/ring entries take the dense fill.
     """
     q = _project_q(p, x, cfg)
     k, v = _project_kv(p, x, cfg)
@@ -146,7 +147,7 @@ def apply_attention_prefill(
     )
     if "kp" in kv_cache:
         kv_cache = cache_lib.fill_paged_cache(kv_cache, k, v, positions,
-                                              block_tables)
+                                              block_tables, layer)
     else:
         kv_cache = cache_lib.fill_attn_cache(kv_cache, k, v, positions)
     return _out_proj(p, o), kv_cache
@@ -163,6 +164,7 @@ def apply_attention_prefill_chunk(
     block_tables: Optional[jax.Array] = None,
     valid: Optional[jax.Array] = None,
     overwrite_from: Optional[jax.Array] = None,
+    layer=0,
 ) -> Tuple[jax.Array, Dict]:
     """Chunked prefill: the chunk attends to every cached chunk 0..N-1 plus
     itself (causally), then its K/V is appended for chunks N+1.. and decode.
@@ -188,6 +190,8 @@ def apply_attention_prefill_chunk(
     branch needs no mask: it appends *first*, so the overlap is overwritten
     in the pool before the gather, and stale positions beyond the window
     exceed every query position (causal masking hides them).
+
+    ``layer`` indexes this layer in a paged entry's stacked pools.
     """
     q = _project_q(p, x, cfg)
     k_new, v_new = _project_kv(p, x, cfg)
@@ -195,8 +199,9 @@ def apply_attention_prefill_chunk(
     k_new = apply_rope(k_new, positions, cfg.rope_theta)
     if "kp" in kv_cache:
         kv_cache = cache_lib.append_paged_cache(
-            kv_cache, k_new, v_new, positions, block_tables, valid)
-        k_all, v_all, k_pos = cache_lib.gather_paged_kv(kv_cache, block_tables)
+            kv_cache, k_new, v_new, positions, block_tables, layer, valid)
+        k_all, v_all, k_pos = cache_lib.gather_paged_kv(
+            kv_cache, block_tables, layer, cfg.resolved_head_dim)
         o = dispatch.flash_attention(
             q, k_all, v_all, q_positions=positions, k_positions=k_pos,
             causal=True, window=window, softcap=cfg.logit_softcap,
@@ -229,6 +234,7 @@ def apply_attention_decode(
     window: int = 0,
     block_tables: Optional[jax.Array] = None,
     update_mask: Optional[jax.Array] = None,
+    layer=0,
 ) -> Tuple[jax.Array, Dict]:
     B = x.shape[0]
     positions = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B,))
@@ -239,10 +245,11 @@ def apply_attention_decode(
     k_new = apply_rope(k_new, pos_b, cfg.rope_theta)
     if "kp" in kv_cache:  # paged: append via block table, attend on the pool
         kv_cache = cache_lib.update_paged_cache(
-            kv_cache, k_new, v_new, positions, block_tables, update_mask)
+            kv_cache, k_new, v_new, positions, block_tables, layer,
+            update_mask)
         o = dispatch.paged_decode_attention(
             q, kv_cache["kp"], kv_cache["vp"],
-            block_tables=block_tables, q_positions=pos_b,
+            block_tables=block_tables, q_positions=pos_b, layer=layer,
             window=window, softcap=cfg.logit_softcap,
         )
         return _out_proj(p, o), kv_cache

@@ -9,7 +9,7 @@ attention KV:
   absolute key positions (``pos``; -1 = unfilled), which makes ring buffers,
   masking, and RoPE-at-write-time uniform across cache kinds.
 * **paged** — a global block pool ``kp``/``vp`` of shape ``(num_blocks,
-  block_size, H, D)`` shared by every slot, addressed through an int32
+  block_size, H*D)`` shared by every slot, addressed through an int32
   block table ``(batch, max_blocks_per_slot)``.  Token at absolute position
   ``p`` of slot ``s`` lives at ``pool[table[s, p // bs], p % bs]``, so a
   slot only consumes the blocks its actual length needs instead of a
@@ -18,6 +18,13 @@ attention KV:
   and freed slots point their whole table row back at it.  No ``pos``
   array is needed — gathered key index ``j`` *is* absolute position ``j``,
   and causal masking hides everything past the slot's length.
+
+  The heads ride in the lanes (``H*D`` last), the layout the paged decode
+  kernel reads, and the model stacks each pool over layers, ``(layers,
+  num_blocks, block_size, H*D)``.  Every paged function here takes that
+  stacked pool and a ``layer`` index and reads or writes it in place at
+  ``[layer, block, offset]``: a pool is never sliced per layer nor
+  reshaped, either of which is a copy of the whole pool on the TPU.
 
 Sliding-window (``local_attn``) caches keep the ring layout in both modes:
 their memory is already bounded by the window, so paging buys nothing.
@@ -409,17 +416,29 @@ def append_attn_cache(cache: Dict, k: jax.Array, v: jax.Array,
 def init_paged_attn_cache(
     num_blocks: int, block_size: int, n_kv: int, head_dim: int, dtype
 ) -> Dict:
-    return {
-        "kp": jnp.zeros((num_blocks, block_size, n_kv, head_dim), dtype),
-        "vp": jnp.zeros((num_blocks, block_size, n_kv, head_dim), dtype),
-    }
+    """One layer's pools, heads in the lanes: ``(num_blocks, block_size,
+    n_kv * head_dim)``.  ``model.init_cache`` stacks them over layers."""
+    shape = (num_blocks, block_size, n_kv * head_dim)
+    return {"kp": jnp.zeros(shape, dtype), "vp": jnp.zeros(shape, dtype)}
+
+
+def is_pool(path) -> bool:
+    """Whether a cache-tree path (``jax.tree_util`` keys) ends at a paged
+    pool leaf: shared by every slot, so it never takes a per-slot slice."""
+    return getattr(path[-1], "key", None) in ("kp", "vp")
+
+
+def _rows(x: jax.Array, pool: jax.Array) -> jax.Array:
+    """(..., H, D) K or V as pool rows (..., H*D) in the pool's dtype."""
+    return x.reshape(*x.shape[:-2], -1).astype(pool.dtype)
 
 
 def fill_paged_cache(
     cache: Dict, k: jax.Array, v: jax.Array, positions: jax.Array,
-    block_tables: jax.Array,
+    block_tables: jax.Array, layer,
 ) -> Dict:
-    """Scatter a full prefill's K/V (B, S, H, D) into pool blocks.
+    """Scatter a full prefill's K/V (B, S, H, D) into pool blocks of
+    ``layer``.
 
     The prompt occupies absolute positions 0..S-1, so row ``b`` fills table
     entries ``0..ceil(S/bs)-1`` of ``block_tables[b]`` in order.  S is
@@ -428,23 +447,25 @@ def fill_paged_cache(
     """
     del positions  # prompt positions are 0..S-1 by construction
     B, S = k.shape[:2]
-    bs = cache["kp"].shape[1]
+    bs = cache["kp"].shape[2]
     nb = -(-S // bs)
     pad = nb * bs - S
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     idx = block_tables[:, :nb].reshape(-1)
-    kb = k.reshape(B * nb, bs, *k.shape[2:]).astype(cache["kp"].dtype)
-    vb = v.reshape(B * nb, bs, *v.shape[2:]).astype(cache["vp"].dtype)
-    return {"kp": cache["kp"].at[idx].set(kb), "vp": cache["vp"].at[idx].set(vb)}
+    kb = _rows(k, cache["kp"]).reshape(B * nb, bs, -1)
+    vb = _rows(v, cache["vp"]).reshape(B * nb, bs, -1)
+    return {"kp": cache["kp"].at[layer, idx].set(kb),
+            "vp": cache["vp"].at[layer, idx].set(vb)}
 
 
 def update_paged_cache(
     cache: Dict, k_new: jax.Array, v_new: jax.Array, positions: jax.Array,
-    block_tables: jax.Array, update_mask: jax.Array = None,
+    block_tables: jax.Array, layer, update_mask: jax.Array = None,
 ) -> Dict:
-    """Write one decoded token's K/V (B, 1, H, D) at per-row ``positions``.
+    """Write one decoded token's K/V (B, 1, H, D) of ``layer`` at per-row
+    ``positions``.
 
     Active slots always have the covering block allocated (admission
     reserves blocks for prompt + budget); idle slots' tables point at the
@@ -456,34 +477,34 @@ def update_paged_cache(
     """
     B = block_tables.shape[0]
     positions = jnp.broadcast_to(jnp.asarray(positions, jnp.int32), (B,))
-    bs = cache["kp"].shape[1]
+    bs = cache["kp"].shape[2]
     rows = jnp.arange(B)
     blk = block_tables[rows, positions // bs]
     if update_mask is not None:
         blk = jnp.where(update_mask, blk, GARBAGE_BLOCK)
     off = positions % bs
-    kp = cache["kp"].at[blk, off].set(k_new[:, 0].astype(cache["kp"].dtype))
-    vp = cache["vp"].at[blk, off].set(v_new[:, 0].astype(cache["vp"].dtype))
+    kp = cache["kp"].at[layer, blk, off].set(_rows(k_new[:, 0], cache["kp"]))
+    vp = cache["vp"].at[layer, blk, off].set(_rows(v_new[:, 0], cache["vp"]))
     return {"kp": kp, "vp": vp}
 
 
 def append_paged_cache(
     cache: Dict, k: jax.Array, v: jax.Array, positions: jax.Array,
-    block_tables: jax.Array, valid: jax.Array = None,
+    block_tables: jax.Array, layer, valid: jax.Array = None,
 ) -> Dict:
-    """Scatter a prompt chunk's K/V (B, C, H, D) at absolute ``positions``
-    (B, C) into pool blocks through the block tables.
+    """Scatter a prompt chunk's K/V (B, C, H, D) of ``layer`` at absolute
+    ``positions`` (B, C) into pool blocks through the block tables.
 
     Unlike ``fill_paged_cache`` (whole prompt, block-aligned from position
     0) the chunk may start and end anywhere inside a block, so each token
     is routed individually: position ``p`` lands at
-    ``pool[table[b, p // bs], p % bs]``.
+    ``pool[layer, table[b, p // bs], p % bs]``.
 
     ``valid`` (B, C) bool, when given, routes masked-off entries to the
     garbage block: the unified mixed-batch step packs ragged per-slot
     chunks into one static-width batch, and a pad column's position may
     exceed the row's allocation (or the whole row may be idle)."""
-    bs = cache["kp"].shape[1]
+    bs = cache["kp"].shape[2]
     idx = positions // bs
     if valid is not None:
         # pad positions can run past the table width; clamp before gather
@@ -492,13 +513,15 @@ def append_paged_cache(
     if valid is not None:
         blk = jnp.where(valid, blk, GARBAGE_BLOCK)
     off = positions % bs
-    kp = cache["kp"].at[blk, off].set(k.astype(cache["kp"].dtype))
-    vp = cache["vp"].at[blk, off].set(v.astype(cache["vp"].dtype))
+    kp = cache["kp"].at[layer, blk, off].set(_rows(k, cache["kp"]))
+    vp = cache["vp"].at[layer, blk, off].set(_rows(v, cache["vp"]))
     return {"kp": kp, "vp": vp}
 
 
-def gather_paged_kv(cache: Dict, block_tables: jax.Array):
-    """Materialize each row's pool blocks as dense K/V plus key positions.
+def gather_paged_kv(cache: Dict, block_tables: jax.Array, layer,
+                    head_dim: int):
+    """Materialize each row's pool blocks of ``layer`` as dense K/V plus key
+    positions.
 
     Returns ``(k, v, k_positions)`` of shapes (B, M*bs, H, D) x2 and
     (B, M*bs) where M is the block-table width.  Gathered index ``j`` *is*
@@ -507,12 +530,14 @@ def gather_paged_kv(cache: Dict, block_tables: jax.Array):
     prompt position and are hidden by causal masking.  Used by the chunked
     prefill (chunk N attends to cached chunks 0..N-1 plus itself);
     decode-side reads go through the scalar-prefetch Pallas kernel
-    instead, which never materializes this gather."""
+    instead, which never materializes this gather.  One gather reads the
+    stacked pool at ``(layer, table)``, so no layer is sliced out first."""
     kp, vp = cache["kp"], cache["vp"]
     B, M = block_tables.shape
-    bs = kp.shape[1]
-    k = kp[block_tables].reshape(B, M * bs, *kp.shape[2:])
-    v = vp[block_tables].reshape(B, M * bs, *vp.shape[2:])
+    bs = kp.shape[2]
+    shape = (B, M * bs, kp.shape[3] // head_dim, head_dim)
+    k = kp[layer, block_tables].reshape(shape)
+    v = vp[layer, block_tables].reshape(shape)
     pos = jnp.broadcast_to(jnp.arange(M * bs, dtype=jnp.int32)[None], (B, M * bs))
     return k, v, pos
 

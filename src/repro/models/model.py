@@ -164,6 +164,7 @@ def _apply_block_seq(
     chunked: bool = False,
     chunk_valid: Optional[jax.Array] = None,
     overwrite_from: Optional[jax.Array] = None,
+    layer=0,
 ) -> Tuple[jax.Array, Optional[Dict]]:
     """Full-sequence block (train / prefill / encoder).
 
@@ -176,6 +177,8 @@ def _apply_block_seq(
     chunks are packed into one static-width batch (unified mixed step):
     attention masks pad keys and cache writes, recurrent/conv states take
     identity steps at pads.
+
+    ``layer`` indexes this block's layer in a paged entry's stacked pools.
     """
     new_entry: Optional[Dict] = None
     if kind in ("attn", "local_attn"):
@@ -187,11 +190,12 @@ def _apply_block_seq(
                     p["attn"], h, cfg, positions, cache_entry["self"],
                     window=window, block_tables=block_tables,
                     valid=chunk_valid, overwrite_from=overwrite_from,
+                    layer=layer,
                 )
             else:
                 a, self_cache = attn_lib.apply_attention_prefill(
                     p["attn"], h, cfg, positions, cache_entry["self"],
-                    window=window, block_tables=block_tables
+                    window=window, block_tables=block_tables, layer=layer,
                 )
             new_entry = {"self": self_cache}
         else:
@@ -268,13 +272,14 @@ def _apply_block_decode(
     cache_entry: Dict,
     block_tables: Optional[jax.Array] = None,
     update_mask: Optional[jax.Array] = None,
+    layer=0,
 ) -> Tuple[jax.Array, Dict]:
     if kind in ("attn", "local_attn"):
         window = cfg.sliding_window if kind == "local_attn" else 0
         h = apply_norm(p["norm1"], x, cfg.norm_eps)
         a, self_cache = attn_lib.apply_attention_decode(
             p["attn"], h, cfg, position, cache_entry["self"], window=window,
-            block_tables=block_tables, update_mask=update_mask
+            block_tables=block_tables, update_mask=update_mask, layer=layer,
         )
         new_entry = dict(cache_entry)
         new_entry["self"] = self_cache
@@ -316,6 +321,57 @@ def _apply_block_decode(
 # ---------------------------------------------------------------------------
 # stack application
 # ---------------------------------------------------------------------------
+#
+# Paged pools (``kp``/``vp``) are stacked over layers and ride through the
+# layer loop in the ``lax.scan`` carry, each layer writing and reading its
+# own ``[layer, ...]`` part in place; every other cache entry is per-layer
+# state and rides as the scan's ``xs``/``ys``.  A pool fed in as ``xs``
+# would be sliced out of the stack and stacked back every layer: two
+# copies of the whole pool per layer and step.
+
+def _split_pools(tree):
+    """``(entries, pools)``: a cache tree's pool leaves apart from the
+    rest, each tree holding ``None`` where the other holds a leaf."""
+    def keep(want):
+        return lambda path, x: x if cache_lib.is_pool(path) == want else None
+
+    return (jax.tree_util.tree_map_with_path(keep(False), tree),
+            jax.tree_util.tree_map_with_path(keep(True), tree))
+
+
+def _join_pools(entries, pools):
+    """Inverse of ``_split_pools``."""
+    return jax.tree.map(lambda e, p: e if p is None else p, entries, pools,
+                        is_leaf=lambda x: x is None)
+
+
+def _scan_layers(body, x, params, cache):
+    """Run ``body(x, params_g, cache_g, g) -> (x, new_cache_g)`` over the
+    ``n`` stacked layer groups, pools in the carry (see above); ``cache``
+    may be None.  ``flags.unroll_scans()`` unrolls the loop, same rule."""
+    entries, pools = _split_pools(cache)
+
+    def step(carry, xs):
+        x, pools = carry
+        gp, gc, g = xs
+        x, nc = body(x, gp, _join_pools(gc, pools), g)
+        nc, pools = _split_pools(nc)
+        return (x, pools), nc
+
+    n = jax.tree.leaves(params)[0].shape[0]
+    xs = (params, entries, jnp.arange(n, dtype=jnp.int32))
+    carry = (x, pools)
+    if flags.unroll_scans():
+        ys = []
+        for g in range(n):
+            carry, nc = step(carry, jax.tree.map(lambda t: t[g], xs))
+            ys.append(nc)
+        ys = jax.tree.map(lambda *ts: jnp.stack(ts), *ys)
+    else:
+        carry, ys = jax.lax.scan(step, carry, xs)
+    x, pools = carry
+    return x, _join_pools(ys, pools)
+
 
 def _apply_stack_seq(
     stack: Dict,
@@ -336,58 +392,34 @@ def _apply_stack_seq(
     fill = cache is not None
     n_groups, n_rest = cfg.layer_groups()
 
-    def group_body(x, group_params, group_cache):
+    def blocks(x, block_params, block_cache, layer, kinds=pattern):
         new_cache = {}
-        for i, kind in enumerate(pattern):
-            entry = group_cache[str(i)] if fill else None
+        for i, kind in enumerate(kinds):
+            entry = block_cache[str(i)] if fill else None
             x, new_entry = _apply_block_seq(
-                group_params[str(i)], cfg, kind, x, positions, entry, memory,
+                block_params[str(i)], cfg, kind, x, positions, entry, memory,
                 causal=causal, fill_cache=fill, block_tables=block_tables,
                 chunked=chunked, chunk_valid=chunk_valid,
-                overwrite_from=overwrite_from,
+                overwrite_from=overwrite_from, layer=layer,
             )
             if fill:
                 new_cache[str(i)] = new_entry
         return x, (new_cache if fill else None)
 
-    if remat:
-        group_body = jax.checkpoint(
-            group_body, policy=jax.checkpoint_policies.nothing_saveable
-        )
+    group_body = (jax.checkpoint(
+        blocks, policy=jax.checkpoint_policies.nothing_saveable)
+        if remat else blocks)
 
     new_cache_tree: Dict[str, Any] = {}
     if n_groups > 0:
-        def scan_fn(x, xs):
-            gp, gc = xs
-            x, nc = group_body(x, gp, gc if fill else None)
-            return x, nc
-
-        xs = (stack["groups"], cache["groups"] if fill else None)
-        if not fill:
-            xs = (stack["groups"], jnp.zeros((n_groups,), jnp.int32))
-        if flags.unroll_scans():
-            caches = []
-            for g in range(n_groups):
-                x, nc = scan_fn(x, jax.tree.map(lambda t: t[g], xs))
-                caches.append(nc)
-            group_caches = (jax.tree.map(lambda *ts: jnp.stack(ts), *caches)
-                            if fill else None)
-        else:
-            x, group_caches = jax.lax.scan(scan_fn, x, xs)
+        x, group_caches = _scan_layers(
+            group_body, x, stack["groups"], cache["groups"] if fill else None)
         if fill:
             new_cache_tree["groups"] = group_caches
     if n_rest > 0:
-        new_rest = {}
-        for i, kind in enumerate(pattern[:n_rest]):
-            entry = cache["rest"][str(i)] if fill else None
-            x, new_entry = _apply_block_seq(
-                stack["rest"][str(i)], cfg, kind, x, positions, entry, memory,
-                causal=causal, fill_cache=fill, block_tables=block_tables,
-                chunked=chunked, chunk_valid=chunk_valid,
-                overwrite_from=overwrite_from,
-            )
-            if fill:
-                new_rest[str(i)] = new_entry
+        # remainder pools carry a unit layer axis (``init_cache``)
+        x, new_rest = blocks(x, stack["rest"], cache["rest"] if fill else None,
+                             0, pattern[:n_rest])
         if fill:
             new_cache_tree["rest"] = new_rest
     x = apply_norm(stack["final_norm"], x, cfg.norm_eps)
@@ -405,36 +437,24 @@ def _apply_stack_decode(
 ) -> Tuple[jax.Array, Dict]:
     pattern = cfg.block_pattern
     n_groups, n_rest = cfg.layer_groups()
+
+    def blocks(x, block_params, block_cache, layer, kinds=pattern):
+        nc = {}
+        for i, kind in enumerate(kinds):
+            x, nc[str(i)] = _apply_block_decode(
+                block_params[str(i)], cfg, kind, x, position,
+                block_cache[str(i)], block_tables, update_mask, layer=layer
+            )
+        return x, nc
+
     new_cache: Dict[str, Any] = {}
     if n_groups > 0:
-        def scan_fn(x, xs):
-            gp, gc = xs
-            nc = {}
-            for i, kind in enumerate(pattern):
-                x, nc[str(i)] = _apply_block_decode(
-                    gp[str(i)], cfg, kind, x, position, gc[str(i)],
-                    block_tables, update_mask
-                )
-            return x, nc
-
-        xs = (stack["groups"], cache["groups"])
-        if flags.unroll_scans():
-            caches = []
-            for g in range(n_groups):
-                x, nc = scan_fn(x, jax.tree.map(lambda t: t[g], xs))
-                caches.append(nc)
-            group_caches = jax.tree.map(lambda *ts: jnp.stack(ts), *caches)
-        else:
-            x, group_caches = jax.lax.scan(scan_fn, x, xs)
-        new_cache["groups"] = group_caches
+        x, new_cache["groups"] = _scan_layers(blocks, x, stack["groups"],
+                                              cache["groups"])
     if n_rest > 0:
-        nr = {}
-        for i, kind in enumerate(pattern[:n_rest]):
-            x, nr[str(i)] = _apply_block_decode(
-                stack["rest"][str(i)], cfg, kind, x, position,
-                cache["rest"][str(i)], block_tables, update_mask
-            )
-        new_cache["rest"] = nr
+        # remainder pools carry a unit layer axis (``init_cache``)
+        x, new_cache["rest"] = blocks(x, stack["rest"], cache["rest"], 0,
+                                      pattern[:n_rest])
     x = apply_norm(stack["final_norm"], x, cfg.norm_eps)
     return x, new_cache
 
@@ -508,7 +528,10 @@ def init_cache(
     pools (``num_blocks`` x ``block_size``; 0 -> worst-case sizing) shared
     by all slots and addressed through the caller's block tables.  Ring
     (sliding-window), recurrent, and cross-attention entries are identical
-    in both layouts.
+    in both layouts.  Every pool is stacked over layers, ``(layers,
+    num_blocks, block_size, H*D)``: over the groups like every entry, and
+    with a unit layer axis for a remainder layer, so that the paged code
+    reads and writes any pool at ``[layer, ...]``.
     """
     assert layout in ("contiguous", "paged"), layout
     pattern = cfg.block_pattern
@@ -537,7 +560,12 @@ def init_cache(
             for i, kind in enumerate(pattern)
         }
     if n_rest > 0:
-        cache["rest"] = {str(i): entry(kind) for i, kind in enumerate(pattern[:n_rest])}
+        cache["rest"] = {
+            str(i): jax.tree_util.tree_map_with_path(
+                lambda path, a: a[None] if cache_lib.is_pool(path) else a,
+                entry(kind))
+            for i, kind in enumerate(pattern[:n_rest])
+        }
     return cache
 
 

@@ -354,7 +354,8 @@ class ServingEngine:
             # (block axes never shard — the host-managed tables index every
             # device's pool identically)
             self.cache = jax.device_put(
-                self.cache, partition_lib.cache_shardings(self.cache, mesh))
+                self.cache, partition_lib.cache_shardings(
+                    self.cache, mesh, num_kv_heads=cfg.num_kv_heads))
         self.slots: List[Optional[Request]] = [None] * max_batch
         # chunked-prefill cursors: _cursors[s] is set while slot s is in the
         # *prefilling* state; _prefill_order is the FCFS service order
@@ -1407,7 +1408,7 @@ class ServingEngine:
         """Swap the template's dummy pools for the live shared pools."""
 
         def pick(path, t, live):
-            return live if path[-1].key in ("kp", "vp") else t
+            return live if cache_lib.is_pool(path) else t
 
         return jax.tree_util.tree_map_with_path(pick, tmpl, live_cache)
 
@@ -1420,7 +1421,7 @@ class ServingEngine:
         ``rest``); scalar bookkeeping passes through."""
 
         def pick(path, leaf):
-            if path[-1].key in ("kp", "vp"):
+            if cache_lib.is_pool(path):
                 return leaf
             axis = 1 if path[0].key == "groups" else 0
             if leaf.ndim <= axis:
@@ -1443,7 +1444,7 @@ class ServingEngine:
         slots = jnp.asarray(slots_for, jnp.int32)
 
         def merge(path, full, part):
-            if path[-1].key in ("kp", "vp"):
+            if cache_lib.is_pool(path):
                 return part
             axis = 1 if path[0].key == "groups" else 0
             if full.ndim <= axis:

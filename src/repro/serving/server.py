@@ -189,9 +189,13 @@ class EngineServer:
         self._thread.start()
 
     def stop_engine(self) -> None:
+        """Stop the engine loop and wait for it to end: the step in flight
+        finishes, with whatever its caller hooked into it (a profiler
+        stop that exports a trace can take many seconds), before the
+        engine is handed back."""
         self._run.clear()
         if self._thread is not None:
-            self._thread.join(timeout=10.0)
+            self._thread.join()
 
     def summary(self) -> Dict:
         """Engine ``latency_summary()`` + server-side counters."""
@@ -361,8 +365,9 @@ class ServerHandle:
     _thread: threading.Thread
 
     def close(self) -> None:
-        """Graceful shutdown: stop the engine loop (flushes buffers), tear
-        down the HTTP site, stop and join the event-loop thread."""
+        """Graceful shutdown: stop the engine loop (finishes its step,
+        flushes buffers), tear down the HTTP site, stop and join the
+        event-loop thread."""
         self.server.stop_engine()
         fut = asyncio.run_coroutine_threadsafe(
             self._runner.cleanup(), self._loop)

@@ -71,14 +71,17 @@ def batch_shardings(batch_shapes, mesh: Mesh):
     return jax.tree.map(leaf, batch_shapes)
 
 
-def cache_shardings(cache_shapes, mesh: Mesh):
+def cache_shardings(cache_shapes, mesh: Mesh, num_kv_heads: int = 0):
     """KV-cache: batch dim on (pod, data).
 
     Leaves under ``groups`` are scan-stacked — batch sits at axis 1; under
     ``rest`` it is axis 0.  Uneven batch dims fall back to replication.
 
     On a tensor-parallel serving mesh (axis ``tp``) KV heads shard over the
-    tp axis — including the paged pool leaves ``kp``/``vp``, whose *block*
+    tp axis — including the paged pool leaves ``kp``/``vp``, (layers, N,
+    bs, Hkv*D), whose lanes split by whole kv heads when ``num_kv_heads``
+    divides the axis and which stay whole otherwise (the decode kernel's
+    per-device view, ``kernels.dispatch._per_device``).  Their *block*
     axis is never sharded (block tables are host-managed and index every
     device's pool identically; each device holds its head-shard of every
     block).  On the training mesh, heads dims stay replicated across
@@ -101,6 +104,10 @@ def cache_shardings(cache_shapes, mesh: Mesh):
         name = keys[-1]
         nd = len(s.shape)
         spec: list = [None] * nd
+        if name in ("kp", "vp"):
+            if has_model and num_kv_heads and num_kv_heads % model_size == 0:
+                spec[nd - 1] = mp_name
+            return NamedSharding(mesh, PartitionSpec(*spec))
         if dp > 1 and nd > b_axis and s.shape[b_axis] % dp == 0:
             spec[b_axis] = data_axes
 
@@ -116,9 +123,7 @@ def cache_shardings(cache_shapes, mesh: Mesh):
         # (sequence-parallel cache — flash-decoding-style partial softmax);
         # recurrent heads, else the state feature dim
         if has_model:
-            if name in ("kp", "vp") and nd >= 4:
-                try_model(nd - 2)                  # pool heads only, never blocks
-            elif name in ("k", "v", "cross_k", "cross_v") and nd >= b_axis + 4:
+            if name in ("k", "v", "cross_k", "cross_v") and nd >= b_axis + 4:
                 try_model(nd - 2, b_axis + 1)      # H, else L
             elif name == "pos" and nd == b_axis + 2:
                 pass                               # must mirror k/v L-sharding? kept replicated

@@ -133,34 +133,81 @@ def test_decode_attention_ring_buffer_window():
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("window", [0, 24])
-def test_paged_decode_attention_matches_oracle(window):
-    """Block-pool kernel (scalar-prefetched block tables) vs the gather
-    oracle, over shuffled non-contiguous physical blocks."""
-    from repro.kernels.decode_attention import ops, ref
-
-    key = jax.random.PRNGKey(11)
-    B, Hq, Hkv, D, bs, nb, N = 3, 8, 2, 64, 16, 4, 14
-    q = _mk(key, (B, 1, Hq, D), jnp.float32)
-    kp = _mk(jax.random.fold_in(key, 1), (N, bs, Hkv, D), jnp.float32)
-    vp = _mk(jax.random.fold_in(key, 2), (N, bs, Hkv, D), jnp.float32)
-    q_lens = [5, 17, 63]
-    rng = np.random.default_rng(0)
-    perm = rng.permutation(np.arange(1, N))  # block 0 reserved (garbage)
+def _shuffled_tables(B, nb, N, bs, q_lens):
+    """Block tables over shuffled non-contiguous physical blocks; block 0
+    (garbage) is never handed out."""
+    perm = np.random.default_rng(0).permutation(np.arange(1, N))
     tables = np.zeros((B, nb), np.int32)
     ptr = 0
     for b, p in enumerate(q_lens):
         need = (p + 1 + bs - 1) // bs
         tables[b, :need] = perm[ptr:ptr + need]
         ptr += need
-    tables = jnp.asarray(tables)
+    return jnp.asarray(tables)
+
+
+@pytest.mark.parametrize("window", [0, 24])
+def test_paged_decode_attention_matches_oracle(window):
+    """Block-pool kernel (scalar-prefetched block tables) vs the gather
+    oracle, over shuffled non-contiguous physical blocks of a one-layer
+    lane-dense pool (1, N, bs, Hkv*D)."""
+    from repro.kernels.decode_attention import ops, ref
+
+    key = jax.random.PRNGKey(11)
+    B, Hq, Hkv, D, bs, nb, N = 3, 8, 2, 64, 16, 4, 14
+    q = _mk(key, (B, 1, Hq, D), jnp.float32)
+    kp = _mk(jax.random.fold_in(key, 1), (1, N, bs, Hkv * D), jnp.float32)
+    vp = _mk(jax.random.fold_in(key, 2), (1, N, bs, Hkv * D), jnp.float32)
+    q_lens = [5, 17, 63]
+    tables = _shuffled_tables(B, nb, N, bs, q_lens)
     qpos = jnp.asarray([[p] for p in q_lens], jnp.int32)
     o_ref = ref.paged_decode_attention(
-        q, kp, vp, block_tables=tables, q_positions=qpos, window=window)
+        q, kp, vp, block_tables=tables, q_positions=qpos, layer=0,
+        window=window)
     o_pal = ops.paged_decode_attention(
-        q, kp, vp, block_tables=tables, q_positions=qpos, window=window,
-        interpret=True)
+        q, kp, vp, block_tables=tables, q_positions=qpos, layer=0,
+        window=window, interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("Hq,Hkv,window", [
+    (4, 4, 0),     # MHA
+    (8, 2, 0),     # GQA 4:1
+    (8, 2, 24),    # GQA under a sliding window
+])
+def test_paged_decode_attention_stacked_pool_layer(Hq, Hkv, window):
+    """The kernel reads one layer of a pool stacked over layers, lane-dense
+    (layers, N, bs, Hkv*D), picked by its scalar-prefetched ``layer``: at a
+    layer other than 0 it matches the reference and a plain attention over
+    that layer's own K/V, gathered by hand from the (N, bs, Hkv, D) data."""
+    from repro.kernels.decode_attention import ops, ref
+
+    key = jax.random.PRNGKey(17)
+    B, D, bs, nb, N, layers, layer = 3, 32, 16, 4, 14, 3, 2
+    q = _mk(key, (B, 1, Hq, D), jnp.float32)
+    k4 = _mk(jax.random.fold_in(key, 1), (layers, N, bs, Hkv, D), jnp.float32)
+    v4 = _mk(jax.random.fold_in(key, 2), (layers, N, bs, Hkv, D), jnp.float32)
+    kp = k4.reshape(layers, N, bs, Hkv * D)
+    vp = v4.reshape(layers, N, bs, Hkv * D)
+    q_lens = [5, 17, 63]
+    tables = _shuffled_tables(B, nb, N, bs, q_lens)
+    qpos = jnp.asarray([[p] for p in q_lens], jnp.int32)
+    L = nb * bs
+    o_plain = ref.decode_attention(
+        q, k4[layer][tables].reshape(B, L, Hkv, D),
+        v4[layer][tables].reshape(B, L, Hkv, D), q_positions=qpos,
+        k_positions=jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L)),
+        window=window)
+    o_ref = ref.paged_decode_attention(
+        q, kp, vp, block_tables=tables, q_positions=qpos,
+        layer=jnp.int32(layer), window=window)
+    o_pal = ops.paged_decode_attention(
+        q, kp, vp, block_tables=tables, q_positions=qpos,
+        layer=jnp.int32(layer), window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(o_ref), np.asarray(o_plain),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_plain),
                                rtol=2e-5, atol=2e-5)
 
 
@@ -178,19 +225,20 @@ def test_paged_decode_attention_matches_contiguous():
     kpos = jnp.broadcast_to(jnp.arange(L)[None], (B, L)).astype(jnp.int32)
     o_contig = ref.decode_attention(q, kc, vc, q_positions=qpos,
                                     k_positions=kpos)
-    # pool: block 0 garbage, rows interleaved — row b block j at 1 + j*B + b
+    # pool: block 0 garbage, rows interleaved — row b block j at 1 + j*B + b;
+    # one layer, lane-dense (1, N, bs, Hkv*D)
     nb = L // bs
     kp = jnp.concatenate([jnp.zeros((1, bs, Hkv, D))] + [
         kc[b, j * bs:(j + 1) * bs][None] for j in range(nb) for b in range(B)
-    ])
+    ]).reshape(1, -1, bs, Hkv * D)
     vp = jnp.concatenate([jnp.zeros((1, bs, Hkv, D))] + [
         vc[b, j * bs:(j + 1) * bs][None] for j in range(nb) for b in range(B)
-    ])
+    ]).reshape(1, -1, bs, Hkv * D)
     tables = jnp.asarray(
         [[1 + j * B + b for j in range(nb)] for b in range(B)], jnp.int32)
     o_paged = ref.paged_decode_attention(
         q, kp.astype(kc.dtype), vp.astype(vc.dtype), block_tables=tables,
-        q_positions=qpos)
+        q_positions=qpos, layer=0)
     np.testing.assert_allclose(np.asarray(o_paged), np.asarray(o_contig),
                                rtol=1e-6, atol=1e-6)
 

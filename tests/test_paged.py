@@ -81,6 +81,31 @@ def test_paged_matches_contiguous_with_sliding_window(hybrid_model):
     assert out_c == out_p and len(out_c) == 5
 
 
+@pytest.mark.parametrize("chunk,unroll", [(8, False), (0, True)])
+def test_paged_remainder_layer_and_unrolled_loop(chunk, unroll):
+    """The pools ride the layer loop's carry in the scanned and the
+    unrolled loop alike, and a remainder layer's pool (a unit layer axis)
+    is written in place too: streams stay identical to contiguous."""
+    from repro.models import flags
+
+    cfg = ModelConfig(
+        name="toy-remainder", num_layers=3, d_model=64, num_heads=4,
+        num_kv_heads=2, d_ff=128, vocab_size=256,
+        block_pattern=("attn", "ffn"), dtype="float32",
+        param_dtype="float32",
+    ).validate()
+    assert cfg.layer_groups() == (1, 1)  # one scanned group, attn left over
+    params, _ = model_lib.init(cfg, jax.random.PRNGKey(3))
+    arrivals = _poisson_arrivals(cfg, n=4, temperature=0.0, seed=5)
+    with flags.use_unroll(unroll):
+        _, out_c = _run_engine(cfg, params, arrivals, "contiguous",
+                               prefill_chunk=chunk)
+        eng, out_p = _run_engine(cfg, params, arrivals, "paged",
+                                 prefill_chunk=chunk)
+    assert eng.cache["rest"]["0"]["self"]["kp"].shape[0] == 1
+    assert out_c == out_p and len(out_c) == 4
+
+
 def test_blocks_freed_and_reused_after_finish(small_model):
     cfg, params = small_model
     eng = ServingEngine(cfg, params, max_batch=2, max_len=64,
