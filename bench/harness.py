@@ -11,10 +11,15 @@ the row reset for every number of admissions in one step), and the ramp
 of the cell's own traffic.  The window then runs ``--seconds``, and its
 drain ``DRAIN_S`` more, only to close out first tokens.
 
+A cell on more than one chip serves over a ``(chips,)`` mesh whose axis
+``tp`` the program shards heads and FFN over (``TP_SERVE_RULES``), its
+weights made sharded.  What the harness reads of the model comes from the
+configuration's family (``bench/arch/<model_type>.py``).
+
 After the window the device's peak memory is read, the engine is freed,
-and the reference (``bench/reference.py``) is run over a sample of the
-requests the window finished; ``correct`` is true when every number
-compared is within its limit.
+and the family's reference (``bench/reference.py``) is run over a sample
+of the requests the window finished, one layer at a time on one device;
+``correct`` is true when every number compared is within its limit.
 """
 
 from __future__ import annotations
@@ -35,7 +40,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import client, gen, window, work
+from bench import arch, client, gen, window, work
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -65,6 +70,11 @@ class Cell:
     cell: Dict          # bench/cells/<name>.json
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    # bench/arch/<model_type>.py: everything the harness reads of the model
+    family: object = dataclasses.field(init=False, repr=False)
+
+    def __post_init__(self):
+        self.family = arch.family(self.config.get("model_type"))
 
     @classmethod
     def load(cls, name: str, root: Path = ROOT) -> "Cell":
@@ -95,28 +105,12 @@ class Run:
     t1: float                 # window closes
     deadline: float           # end of the drain
     probe: object             # bench.probe.Probe
-    sizes: work.Sizes
-    peak: Dict
+    work: object              # the family's work(c): the whole cell's counts
+    peak: Dict                # one chip's peaks
     compiles: int             # backend compilations inside the window
+    chips: int = 1            # the cell's chips
     trace: Optional[Dict] = None   # trace_reduce.reduce() of the traced steps
     traced: List = dataclasses.field(default_factory=list)  # probe.Step
-
-
-def model_config(c: Dict):
-    """The program's ``ModelConfig`` for a configuration file: the registry
-    entry of its ``arch``, with every size taken from the file."""
-    from repro.configs import get_config
-
-    heads = c["num_attention_heads"]
-    return get_config(c["arch"]).replace(
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=heads, num_kv_heads=c["num_key_value_heads"],
-        head_dim=c["hidden_size"] // heads, d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], rope_theta=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]),
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        qkv_bias=bool(c["architecture"]["qkv_bias"]),
-        dtype=c["torch_dtype"], param_dtype=c["torch_dtype"])
 
 
 def _check_tree(cfg, weights) -> None:
@@ -207,16 +201,17 @@ class Served:
     probe: object
     handle: object
     params: Dict
-    sizes: work.Sizes
+    work: object
     devices: List
     peak: Dict
     compiles: object
 
 
 def serve(c: Cell, seed: int, *, require_tpu: bool = True) -> Served:
-    """Set-up up to the traffic: the weights from ``seed``, the engine,
-    its warm-up and its server.  Raises ``NoChip`` first where there is
-    no TPU (unless ``require_tpu`` is off, for the CPU tests)."""
+    """Set-up up to the traffic: the weights from ``seed``, the engine
+    (over a mesh of the cell's chips where it has more than one), its
+    warm-up and its server.  Raises ``NoChip`` first where there is no TPU
+    (unless ``require_tpu`` is off, for the CPU tests)."""
     import jax
 
     devices = jax.devices()
@@ -229,18 +224,29 @@ def serve(c: Cell, seed: int, *, require_tpu: bool = True) -> Served:
                       else "TPU v5 lite")
 
     from bench import probe as probe_lib
-    from bench import weights as weights_lib
     from repro.serving.engine import ServingEngine
     from repro.serving.sampling import SamplingParams
     from repro.serving.server import start_http_server
 
     compiles = probe_lib.Compiles()
-    cfg = model_config(c.config)
-    sizes = work.Sizes.from_config(c.config)
-    params = weights_lib.make(sizes, seed, c.config["torch_dtype"])
+    fam = c.family
+    cfg = fam.program_config(c.config)
+    counts = fam.work(c.config)
+    mesh = axes = shardings = None
+    if c.chips > 1:
+        from repro.launch.mesh import make_mesh
+        from repro.models import model as model_lib
+        from repro.sharding import partition, rules
+
+        mesh = make_mesh((c.chips,), ("tp",), devices=devices[:c.chips])
+        shapes, axes = model_lib.param_axes(cfg)
+        shardings = partition.param_shardings(axes, shapes, mesh,
+                                              rules.TP_SERVE_RULES)
+    params = fam.weights(c.config, seed, c.config["torch_dtype"], shardings)
     _check_tree(cfg, params)
     eng_kw = dict(c.cell["engine"])
-    engine = ServingEngine(cfg, params, seed=seed % (1 << 31), **eng_kw)
+    engine = ServingEngine(cfg, params, seed=seed % (1 << 31), mesh=mesh,
+                           param_axes=axes, **eng_kw)
     probe = probe_lib.Probe(engine)
 
     # warm-up: every number of admissions in one step, from 1 to
@@ -249,7 +255,7 @@ def serve(c: Cell, seed: int, *, require_tpu: bool = True) -> Served:
     rng = np.random.default_rng(0)
     for n in range(1, int(eng_kw["max_batch"]) + 1):
         for _ in range(n):
-            engine.submit(rng.integers(0, sizes.vocab, WARM_PROMPT,
+            engine.submit(rng.integers(0, counts.vocab, WARM_PROMPT,
                                        dtype=np.int32),
                           SamplingParams(max_new_tokens=2 if n == 1 else 1))
         engine.run()
@@ -257,7 +263,7 @@ def serve(c: Cell, seed: int, *, require_tpu: bool = True) -> Served:
 
     handle = start_http_server(engine)
     probe.wrap_hook()
-    return Served(engine, probe, handle, params, sizes, devices, peak,
+    return Served(engine, probe, handle, params, counts, devices, peak,
                   compiles)
 
 
@@ -267,7 +273,7 @@ def offer(s: Served, c: Cell, conn, load: Dict, seed: int, seconds: float,
     ramp, the window and its drain.  Returns (plan, records, t0, t1,
     deadline), the window being ``[t0, t1)``."""
     plan = gen.schedule(c.mix, load, seed=seed, seconds=seconds,
-                        drain=DRAIN_S, vocab=s.sizes.vocab)
+                        drain=DRAIN_S, vocab=s.work.vocab)
     bodies = [client.request_body(p.prompt, p.max_new) for p in plan]
     ramp = float(load.get("ramp_s", 0.0))
     t0 = time.perf_counter() + LEAD_S + ramp
@@ -285,11 +291,11 @@ def _measure(c: Cell, seed: int, seconds: float, trace: bool, conn, *,
              started: float, require_tpu: bool, control: bool) -> Dict:
     import jax
 
-    from bench import reference, trace_reduce
+    from bench import trace_reduce
 
     s = serve(c, seed, require_tpu=require_tpu)
     engine, probe, compiles, params = s.engine, s.probe, s.compiles, s.params
-    sizes, devices, peak = s.sizes, s.devices, s.peak
+    devices, peak = s.devices, s.peak
     kind = devices[0].device_kind
     max_len = int(c.cell["engine"]["max_len"])
     load = c.cell["load"]
@@ -317,9 +323,9 @@ def _measure(c: Cell, seed: int, seconds: float, trace: bool, conn, *,
             trace_reduce.find_xplane(tdir)))
         shutil.rmtree(tdir, ignore_errors=True)
         run = Run(records=records, t0=t0, t1=t1, deadline=deadline,
-                  probe=probe, sizes=sizes, peak=peak,
-                  compiles=compiles.between(t0, t1), trace=reduced,
-                  traced=probe.traced)
+                  probe=probe, work=s.work, peak=peak,
+                  compiles=compiles.between(t0, t1), chips=c.chips,
+                  trace=reduced, traced=probe.traced)
         metrics = {}
         for m in c.per_layer:
             v = _reader(m["name"])(run)
@@ -341,6 +347,10 @@ def _measure(c: Cell, seed: int, seconds: float, trace: bool, conn, *,
     result["device"] = device
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # marks a run whose client fell behind the cell's schedule
+    result["schedule"] = {"late_sends": late["late_sends"],
+                          "late_max_ms": late["late_max_ms"],
+                          "limit_ms": window.LATE_S * 1e3}
 
     # the check, once the window is over and the engine is freed
     engine.cache = None
@@ -355,8 +365,9 @@ def _measure(c: Cell, seed: int, seconds: float, trace: bool, conn, *,
     errors = sum(bool(r["error"]) for r in records)
     sample = [(plan[r["idx"]].prompt, r["tokens"])
               for r in _sample(records, plan, t0, deadline, seed)]
-    gap = reference.worst_gap(params, c.config, sample,
-                              _ref_length(max_len)) if sample else None
+    fam = c.family
+    gap = fam.worst_gap(params, c.config, sample,
+                        _ref_length(max_len)) if sample else None
     limits = c.cell["limits"]
     checks = {
         "max_logit_gap": {"value": gap, "limit": limits["max_logit_gap"]},
@@ -372,9 +383,8 @@ def _measure(c: Cell, seed: int, seconds: float, trace: bool, conn, *,
         # the control in the program's place: its tokens are the ones
         # served, its gap is read where the program's was, and the same
         # judgement decides
-        ctl = reference.worst_gap(params, c.config, sample,
-                                  _ref_length(max_len),
-                                  quant="fp8") if sample else None
+        ctl = fam.worst_gap(params, c.config, sample, _ref_length(max_len),
+                            quant="fp8") if sample else None
         ctl_checks = dict(checks, max_logit_gap=dict(
             checks["max_logit_gap"], value=ctl))
         detail["control"] = {"correct": judge(ctl_checks),
@@ -418,6 +428,12 @@ def main(args, *, started: float) -> int:
     (out / f"{args.workload}.{args.seed}.trace{args.trace}.json").write_text(
         json.dumps({"result": result, "detail": detail}, indent=1))
     print("bench: " + json.dumps(detail), flush=True)
+    late = result["schedule"]
+    if late["late_sends"]:
+        print(f"bench: off schedule: the client sent {late['late_sends']} "
+              f"request(s) more than {late['limit_ms']:.0f} ms late, the "
+              f"latest {late['late_max_ms']:.0f} ms; this run offered other "
+              "load than the cell states", file=sys.stderr, flush=True)
     for name, v in result["checks"].items():
         print(f"check {name}: {v['value']} (limit {v['limit']})",
               file=sys.stderr, flush=True)

@@ -8,7 +8,9 @@ the checkout.  The run needs a TPU with at least as many chips as the cell
 asks for; without one it exits non-zero and prints no result.  The last
 line of standard output is the result: one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` also
-``breakdown``, and last ``checks``, each number compared beside its limit.
+``breakdown``, then ``schedule`` (the client's sends off the cell's
+schedule, ``bench/window.py:lateness``), and last ``checks``, each number
+compared beside its limit.
 ``--out`` names a directory for the run's file of details (default
 ``bench_runs/`` in the checkout).
 

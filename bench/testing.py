@@ -23,11 +23,12 @@ SMOKE = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
              torch_dtype="float32")
 
 
-def cell(cfg: Dict, *, limit: float = 1e-3) -> harness.Cell:
-    """A tiny cell over ``cfg`` with the benchmark's metric lists."""
+def cell(cfg: Dict, *, limit: float = 1e-3, chips: int = 1) -> harness.Cell:
+    """A tiny cell over ``cfg`` on ``chips`` devices with the benchmark's
+    metric lists."""
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     return harness.Cell(
-        name="tiny", chips=1, config=cfg,
+        name="tiny", chips=chips, config=cfg,
         mix={"prompt": {"dist": "lognormal", "mean": 24, "sigma": 0.8,
                         "low": 4, "high": 64},
              "output": {"dist": "lognormal", "mean": 8, "sigma": 0.8,

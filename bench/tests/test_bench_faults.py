@@ -58,6 +58,7 @@ def test_sound_run_is_correct(monkeypatch, seed):
                                  "output_tokens_per_s", "setup_s"}
     assert list(r)[-1] == "checks"
     assert r["checks"]["max_logit_gap"]["value"] <= 1e-3
+    assert set(r["schedule"]) == {"late_sends", "late_max_ms", "limit_ms"}
 
 
 @pytest.mark.parametrize("fault", [_altered_token, _state_unchanged])

@@ -1,4 +1,4 @@
-"""The benchmark's float32 reference against the program's model, in
+"""The qwen2 family's float32 reference against the program's model, in
 float32 at each configuration's smoke size, on the CPU."""
 
 import sys
@@ -12,9 +12,11 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import harness, reference, testing, weights, work  # noqa: E402
+from bench import arch, harness, reference, testing  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.models import model as model_lib  # noqa: E402
+
+qwen2 = arch.family("qwen2")
 
 
 def smoke_config(name):
@@ -31,16 +33,16 @@ def smoke_config(name):
 @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "qwen2.5-1.5b"])
 def test_reference_matches_the_program_in_float32(name):
     c = smoke_config(name)
-    cfg = harness.model_config(c)
-    params = weights.make(work.Sizes.from_config(c), 7, "float32")
+    cfg = qwen2.program_config(c)
+    params = qwen2.weights(c, 7, "float32")
     harness._check_tree(cfg, params)
     ids = np.random.default_rng(0).integers(0, c["vocab_size"], 40)
     with jax.default_matmul_precision("highest"):
         want = model_lib.forward_train(
             cfg, params, {"tokens": jnp.asarray(ids[None], jnp.int32)},
             remat=False)[0]
-        a = reference.Arch.from_config(c)
-        x = reference._hidden(params, a, ids, 64, "none")[:40]
+        a = qwen2.Arch.from_config(c)
+        x = qwen2.hidden(params, a, np.pad(ids, (0, 24)), "none")[:40]
         got = reference._head(x, params["decoder"]["final_norm"]["scale"],
                               params["embed"]["table"], a.eps, "none")
     scale = float(jnp.max(jnp.abs(want)))
@@ -51,19 +53,18 @@ def test_reference_matches_the_program_in_float32(name):
 
 def test_served_gap_is_zero_for_the_references_own_greedy_tokens():
     c = smoke_config("qwen2.5-1.5b")
-    params = weights.make(work.Sizes.from_config(c), 3, "float32")
-    a = reference.Arch.from_config(c)
+    params = qwen2.weights(c, 3, "float32")
+    a = qwen2.Arch.from_config(c)
     prompt = list(np.random.default_rng(1).integers(0, 256, 12))
     served = []
     for _ in range(6):  # greedy decode by re-running the whole forward
-        x = reference._hidden(params, a, np.asarray(prompt + served), 64,
-                              "none")
+        ids = np.asarray(prompt + served, np.int32)
+        x = qwen2.hidden(params, a, np.pad(ids, (0, 64 - len(ids))), "none")
         logits = reference._head(
             x, params["decoder"]["final_norm"]["scale"],
             params["embed"]["table"], a.eps, "none")
         served.append(int(jnp.argmax(logits[len(prompt) + len(served) - 1])))
-    gaps = reference.served_gaps(params, c, prompt, served, 64)
+    gaps = qwen2.served_gaps(params, c, prompt, served, 64)
     assert gaps.shape == (6,) and float(gaps.max()) == 0.0
     wrong = [(t + 1) % 256 for t in served]
-    assert float(reference.served_gaps(params, c, prompt, wrong, 64).max()) \
-        > 0.0
+    assert float(qwen2.served_gaps(params, c, prompt, wrong, 64).max()) > 0.0

@@ -79,6 +79,15 @@ def test_lateness_reports_the_client_falling_behind():
     assert late["late_max_ms"] == pytest.approx(100.0)
 
 
+def test_lateness_counts_the_sends_off_the_schedule():
+    recs = [rec(1.0, [], sent=1.002), rec(2.0, [], sent=2.05),
+            rec(3.0, [], sent=4.65), rec(4.0, [], sent=4.65)]
+    late = window.lateness(recs)
+    assert late["late_sends"] == 2
+    assert late["late_max_ms"] == pytest.approx(1650.0)
+    assert window.lateness([])["late_sends"] == 0
+
+
 MIXES = {
     "chat": {"dist": "lognormal", "mean": 512, "sigma": 0.8, "low": 32,
              "high": 3072},

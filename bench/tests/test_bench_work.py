@@ -1,5 +1,5 @@
-"""The work-counting functions against hand counts for both configurations,
-and the table of peaks.  No JAX, no chip."""
+"""The qwen2 family's work counts against hand counts for both Qwen
+configurations, and the shared table of peaks and roofline.  No chip."""
 
 import json
 import sys
@@ -10,7 +10,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import work  # noqa: E402
+from bench import arch, work  # noqa: E402
 
 
 # the GQA case: Qwen2.5-1.5B's published sizes on the benchmark's file
@@ -23,7 +23,7 @@ def sizes(name):
                    .read_text())
     if name == "qwen2.5-1.5b":
         c.update(GQA)
-    return work.Sizes.from_config(c)
+    return arch.family(c["model_type"]).work(c)
 
 
 # Hand counts.  qwen1.5-0.5b: per layer Q, K, V, O are 1024 x 1024 each
@@ -42,11 +42,13 @@ KV_PER_TOKEN = {"qwen1.5-0.5b": 24 * 2 * 16 * 64 * 2,     # 98,304
 
 @pytest.mark.parametrize("name", sorted(PARAMS))
 def test_nonembed_params_match_hand_counts(name):
-    assert work.nonembed_params(sizes(name)) == PARAMS[name]
+    assert sizes(name).nonembed_params == PARAMS[name]
 
 
 def test_published_kv_bytes_per_token():
     assert KV_PER_TOKEN == {"qwen1.5-0.5b": 98_304, "qwen2.5-1.5b": 28_672}
+    assert {n: sizes(n).kv_bytes_per_token for n in KV_PER_TOKEN} == \
+        KV_PER_TOKEN
 
 
 @pytest.mark.parametrize("name", sorted(PARAMS))
@@ -54,7 +56,11 @@ def test_decode_kv_bytes_at_a_position(name):
     s = sizes(name)
     # position 999 reads 1000 cached tokens, plus its query and output
     qo = s.layers * 2 * s.heads * s.head_dim * 2
-    assert work.decode_kv_bytes(s, 999) == 1000 * KV_PER_TOKEN[name] + qo
+    assert s.decode_kv_bytes(999) == 1000 * KV_PER_TOKEN[name] + qo
+    # one decode row's attention: QK^T and PV over 1000 keys, 4 FLOPs per
+    # head dimension per key per layer: 4 x 24 x 16 x 64 and 4 x 28 x 12 x 128
+    per_key = {"qwen1.5-0.5b": 98_304, "qwen2.5-1.5b": 172_032}[name]
+    assert s.decode_attn_flops(999) == 1000 * per_key
 
 
 @pytest.mark.parametrize("name", sorted(PARAMS))
@@ -62,22 +68,26 @@ def test_token_and_prompt_flops(name):
     s = sizes(name)
     attn_per_key = 4 * s.layers * s.heads * s.head_dim
     head = 2 * s.d_model * 151_936
-    assert work.token_flops(s, 0, False) == 2 * PARAMS[name] + attn_per_key
-    assert work.token_flops(s, 9, True) == (2 * PARAMS[name]
-                                            + 10 * attn_per_key + head)
+    assert s.vocab == 151_936
+    assert s.token_flops(0, False) == 2 * PARAMS[name] + attn_per_key
+    assert s.token_flops(9, True) == (2 * PARAMS[name]
+                                      + 10 * attn_per_key + head)
     # a 3-token prompt: positions 0, 1, 2 attend to 1 + 2 + 3 keys, and
     # only the last samples
-    assert work.prompt_flops(s, 3) == (3 * 2 * PARAMS[name]
-                                       + 6 * attn_per_key + head)
-    assert work.prompt_attn_flops(s, 3) == 6 * attn_per_key
-    assert work.prompt_attn_flops(s, 4096) == attn_per_key * 4096 * 4097 // 2
+    assert s.prompt_flops(3) == (3 * 2 * PARAMS[name]
+                                 + 6 * attn_per_key + head)
+    assert s.prompt_attn_flops(3) == 6 * attn_per_key
+    assert s.prompt_attn_flops(4096) == attn_per_key * 4096 * 4097 // 2
+    # q, k, v and the output of each real token, bf16, every layer
+    per_tok = (2 * s.heads + 2 * s.kv_heads) * s.head_dim * 2
+    assert s.prompt_attn_bytes(3) == s.layers * 3 * per_tok
 
 
 def test_prompt_flops_sum_token_flops():
     s = sizes("qwen2.5-1.5b")
     n = 37
-    by_token = sum(work.token_flops(s, p, p == n - 1) for p in range(n))
-    assert work.prompt_flops(s, n) == by_token
+    by_token = sum(s.token_flops(p, p == n - 1) for p in range(n))
+    assert s.prompt_flops(n) == by_token
 
 
 def test_decode_position_follows_the_engine():

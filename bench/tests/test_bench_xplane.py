@@ -170,10 +170,11 @@ def _served():
     from repro.serving.sampling import SamplingParams
 
     c = testing.cell(testing.config("qwen1.5-0.5b", **testing.SMOKE))
-    cfg = harness.model_config(c.config)
+    cfg = c.family.program_config(c.config)
     params, _ = model_lib.init(cfg, jax.random.PRNGKey(0))
     engine = ServingEngine(cfg, params, seed=0, **c.cell["engine"])
     probe = probe_lib.Probe(engine)
+    probe.wrap_hook()
     rng = np.random.default_rng(0)
     uids = [engine.submit(rng.integers(0, 256, n, dtype=np.int32),
                           SamplingParams(max_new_tokens=5))
@@ -181,7 +182,7 @@ def _served():
     engine.run()
     records = [{"uid": u, "due": 1.0 + i} for i, u in enumerate(uids)]
     run = harness.Run(records=records, t0=0.0, t1=100.0, deadline=105.0,
-                      probe=probe, sizes=None, peak={}, compiles=0,
+                      probe=probe, work=None, peak={}, compiles=0,
                       traced=probe.steps)
     return run, engine
 
@@ -212,3 +213,22 @@ def test_the_span_readers_read_nothing_from_an_engine_without_spans():
     del engine.spans
     for name in ("submit_wait_ms", "prefill_wait_ms", "step_host_ms"):
         assert _read(name, run) is None, name
+
+
+def test_the_work_readers_count_per_chip():
+    # the family's counts are the whole cell's: the same steps in the same
+    # wall time read, on four chips, a quarter of one chip's share of the
+    # peak
+    from bench import testing, work
+
+    run, _ = _served()
+    c = testing.config("qwen1.5-0.5b", **testing.SMOKE)
+    run.work = testing.cell(c).family.work(c)
+    run.peak = work.peaks("TPU v5 lite")
+    run.t0, run.t1 = run.traced[0].t0, run.traced[-1].t1
+    read = {}
+    for chips in (1, 4):
+        run.chips = chips
+        read[chips] = _read("step_mfu", run)
+    assert read[1] is not None and read[1] > 0, read
+    assert read[4] == pytest.approx(read[1] / 4)

@@ -20,6 +20,8 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Sequence
 
+LATE_S = 0.1  # a send later than this is off the schedule (a few steps)
+
 
 def percentile(xs: Sequence[float], q: float) -> float:
     """Nearest-rank percentile, ``q`` in [0, 100]: the smallest value with
@@ -94,10 +96,14 @@ def summarize(records: Sequence[Dict], t0: float, t1: float,
 
 
 def lateness(records: Sequence[Dict]) -> Dict:
-    """How late the client sent against the due times."""
+    """How late the client sent against the due times, and how many sends
+    were more than ``LATE_S`` late: each of those left the cell's
+    schedule, so the run offered other load than the cell states."""
     late = sorted(r["sent"] - r["due"] for r in records if r["sent"] > 0)
     if not late:
-        return {"late_p50_ms": 0.0, "late_p99_ms": 0.0, "late_max_ms": 0.0}
+        return {"late_p50_ms": 0.0, "late_p99_ms": 0.0, "late_max_ms": 0.0,
+                "late_sends": 0}
     return {"late_p50_ms": percentile(late, 50) * 1e3,
             "late_p99_ms": percentile(late, 99) * 1e3,
-            "late_max_ms": late[-1] * 1e3}
+            "late_max_ms": late[-1] * 1e3,
+            "late_sends": sum(x > LATE_S for x in late)}
